@@ -320,7 +320,7 @@ L, SYNC, SWEEPS = %(L)d, %(SYNC)d, %(SWEEPS)d
 g = ea3d(L, seed=0)
 col = lattice3d_coloring(L)
 labels = slab_partition(L, 2)
-mesh = make_mesh((2,), ("data",), axis_types=auto_axes(2))
+mesh = make_mesh((2,), ("data",), axis_types=auto_axes(1))
 h = make_engine("dsim_dist", g, coloring=col, K=2, labels=labels,
                 mesh=mesh, rng="lfsr", precision="int8", replicas=1,
                 degrade="stale_hold:%(SWEEPS)d")
@@ -386,7 +386,8 @@ print("DEGJSON" + json.dumps(out, default=float))
 
 def _degraded_mesh_bench(sweeps: int) -> dict:
     """Degraded arm of the flip-rate record: a REAL 2-device dsim_dist
-    mesh (forced host platform device count, hence the subprocess) under
+    mesh (forced host platform device count, hence the subprocess, which
+    is pinned to the CPU: the parent already holds any chip) under
     ``stale_hold`` with 0/10/30% of boundary exchanges dropped at the
     engine fault site — residual-energy decay per arm plus the
     staleness-vs-eta accounting (effective_eta = clean measured eta x
@@ -397,6 +398,7 @@ def _degraded_mesh_bench(sweeps: int) -> dict:
     import sys
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=2").strip()
     env["PYTHONPATH"] = os.pathsep.join(

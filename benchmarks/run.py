@@ -34,6 +34,8 @@ def main() -> None:
                     help="replica batch (R independent chains per call) for "
                          "engine-aware benchmarks")
     args = ap.parse_args()
+    from repro.cache import enable_compile_cache
+    enable_compile_cache()
 
     mods = args.only if args.only else MODULES
     print("name,us_per_call,derived")

@@ -145,4 +145,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -157,4 +157,6 @@ def crash_recover_demo():
 
 
 if __name__ == "__main__":
+    from repro.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -16,6 +16,8 @@ import sys
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    from repro.cache import enable_compile_cache
+    enable_compile_cache()
     if len(sys.argv) == 1:
         sys.argv += ["--arch", "mamba2-370m", "--reduced", "--steps", "120",
                      "--batch", "8", "--seq", "64", "--ckpt", "/tmp/repro_ck",

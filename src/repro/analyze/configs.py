@@ -35,7 +35,7 @@ _ITERS, _S = 4, 4
 
 
 def _problems():
-    from jax.sharding import AbstractMesh
+    from repro.compat import abstract_mesh
     from repro.core.coloring import greedy_coloring
     from repro.core.dsim import build_partitioned
     from repro.core.graph import random_regular
@@ -48,7 +48,7 @@ def _problems():
     prob = build_partitioned(g, col, np.asarray(labels, np.int32), _K)
     lat = build_ea3d_lattice(8, seed=5)
     return (g, prob, lat,
-            AbstractMesh((("data", _K),)), AbstractMesh((("x", _K),)))
+            abstract_mesh((_K,), ("data",)), abstract_mesh((_K,), ("x",)))
 
 
 def _dist_payload(eng):
@@ -210,7 +210,7 @@ def build_audits() -> Tuple[List[ChunkAudit], List[Tuple[str, str]]]:
             working_set = (
                 fused_working_set_bytes(
                     eng.brick, lat.n_colors, precision=prec,
-                    lanes=eng.replicas),
+                    lanes=eng.replicas, tiled=False),
                 tuple(eng.brick))
         if degrade:
             counters["seq"] = _STATE_LEAVES[engine]

@@ -1,76 +1,51 @@
-"""Version tolerance for the jax sharding API surface.
+"""The jax sharding API surface, in one place.
 
-The repo targets the modern API (``jax.shard_map``, ``jax.make_mesh(...,
-axis_types=...)``, ``jax.sharding.set_mesh``); older installations (such as
-the 0.4.x line) expose the same functionality under different names or not
-at all.  Everything sharding-adjacent goes through this module so the rest
-of the codebase is written once against one surface:
+Written against the installed jax (0.9): ``jax.shard_map``,
+``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.set_mesh`` and
+``AbstractMesh(axis_sizes, axis_names)``.  Everything sharding-adjacent
+goes through this module so a future API move is a change in one file:
 
   shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=False)
   make_mesh(shape, axes, axis_types=None, devices=None)
+  abstract_mesh(shape, axes)  -- device-free mesh for tracing/auditing
   set_mesh(mesh)          -- context manager
-  ambient_mesh()          -- abstract mesh if set, else the physical one
-  mesh_is_auto(mesh)      -- True iff every axis is Auto (or untyped)
-  AxisType                -- enum with .Auto (polyfilled when absent)
+  ambient_mesh()          -- abstract mesh if set, else None
+  mesh_is_auto(mesh)      -- True iff every axis is Auto
+  auto_axes(n)            -- n Auto axis types
 """
 
 from __future__ import annotations
 
-import enum
 from typing import Optional, Sequence
 
-import numpy as np
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-__all__ = ["shard_map", "make_mesh", "set_mesh", "ambient_mesh",
-           "mesh_is_auto", "AxisType", "HAS_NEW_SHARDING"]
-
-HAS_NEW_SHARDING = hasattr(jax, "shard_map")
-
-
-if hasattr(jax.sharding, "AxisType"):
-    AxisType = jax.sharding.AxisType
-else:
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+__all__ = ["shard_map", "make_mesh", "abstract_mesh", "set_mesh",
+           "ambient_mesh", "mesh_is_auto", "auto_axes", "AxisType"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with a fallback to the experimental implementation.
-
-    ``check_vma`` maps onto the legacy ``check_rep`` flag (both gate the
-    replication/varying-manual-axes checker).
-    """
-    if HAS_NEW_SHARDING:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               axis_types=None, devices=None) -> Mesh:
-    """``jax.make_mesh`` accepting (and dropping, when unsupported) axis_types."""
-    if axis_types is not None:
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 axis_types=tuple(axis_types),
-                                 devices=devices)
-        except TypeError:
-            pass
-    try:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             devices=devices)
-    except (TypeError, AttributeError):
-        devs = list(jax.devices()) if devices is None else list(devices)
-        n = int(np.prod(tuple(axis_shapes)))
-        return Mesh(np.asarray(devs[:n]).reshape(tuple(axis_shapes)),
-                    tuple(axis_names))
+    """``jax.make_mesh``; ``axis_types`` must name one type per axis."""
+    if axis_types is not None and len(axis_types) != len(axis_names):
+        raise ValueError(
+            f"{len(axis_types)} axis_types for {len(axis_names)} mesh "
+            f"axes {tuple(axis_names)}")
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=None if axis_types is None
+                         else tuple(axis_types), devices=devices)
+
+
+def abstract_mesh(axis_shapes: Sequence[int],
+                  axis_names: Sequence[str]) -> AbstractMesh:
+    """A device-free mesh: collectives trace over it without devices."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
 
 
 def auto_axes(n: int):
@@ -79,41 +54,18 @@ def auto_axes(n: int):
 
 
 def set_mesh(mesh: Mesh):
-    """Ambient-mesh scope: ``jax.sharding.set_mesh`` or the legacy
-    ``with mesh:`` thread-resources context (which serves the same role for
-    PartitionSpec-based ``with_sharding_constraint``)."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh  # Mesh is a context manager on older jax
+    """Ambient-mesh scope (``jax.sharding.set_mesh``)."""
+    return jax.sharding.set_mesh(mesh)
 
 
-def ambient_mesh() -> Optional[Mesh]:
+def ambient_mesh():
     """The mesh of the enclosing set_mesh scope, or None."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        try:
-            m = jax.sharding.get_abstract_mesh()
-        except Exception:
-            return None
-        if m is None or not getattr(m, "axis_names", ()):
-            return None
-        return m
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if m is None or m.empty:
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.axis_names:
         return None
     return m
 
 
 def mesh_is_auto(mesh) -> bool:
-    """True iff no axis of ``mesh`` is Manual/Explicit (untyped counts as
-    Auto — the legacy mesh has no axis types at all)."""
-    try:
-        return all(t == AxisType.Auto
-                   for t in getattr(mesh, "axis_types", ()))
-    except Exception:
-        return False
-
-
+    """True iff no axis of ``mesh`` is Manual/Explicit."""
+    return all(t == AxisType.Auto for t in mesh.axis_types)

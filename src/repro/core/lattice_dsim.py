@@ -47,60 +47,105 @@ from repro.kernels.ops import (pbit_update_op, pbit_sweep_op,
                                pbit_bitplane_sweep_op, brick_energy_op)
 
 __all__ = ["LatticeDSIM", "LatticeState", "BitplaneLatticeState",
-           "fused_working_set_bytes", "fused_brick_ceiling"]
+           "fused_working_set_bytes", "fused_brick_ceiling",
+           "tiled_working_set_bytes", "pick_x_tile"]
 
-# Per-site VMEM bytes of the single-block fused kernel (DESIGN.md
-# "VMEM working-set math"): f32 path = 7 f32 coupling arrays + in/out spins
-# (int8) + in/out LFSR (u32) + n_colors parity masks; int8 path = the same
-# with the couplings at 1 B/site.  The bitplane path packs 32 replica lanes
-# per uint32 word: in/out spin words (8 B/site for ALL lanes), in/out
-# per-lane LFSR columns (8 B/site/lane), 12 sign/nonzero planes + base
-# (52 B/site) and uint32 color masks (4 B/site each) — per *lane* it is the
-# densest layout of the three.  Halo planes and the threshold LUT are
-# O(B^(2/3)) / O(1) and added separately.
-_PER_SITE_BYTES = {"f32": 38, "int8": 17}
-_LUT_ROWS_NOMINAL = 32          # staircase entries assumed for init-time sizing
+# VMEM working-set model (DESIGN.md "VMEM working-set math").  Mosaic keeps
+# a brick as (By, Bz) planes with z on the 128 lanes and y on sublanes
+# packed 4/itemsize deep (8 rows of 32-bit words, 32 rows of int8), so a
+# plane costs its padded tile bytes, not By*Bz*itemsize: a 100^3 int8
+# brick pays 128*128 B per x-plane, a 16^3 one 32*128 B.  Each kernel's
+# buffers are counted per x-plane as (int8 planes, 32-bit planes):
+#   fused f32    n_c masks + in/out spins  |  h + 6 weights + in/out LFSR
+#   fused int8   n_c masks + in/out spins + h_q + 6 w_q  |  in/out LFSR
+#   bitplane     —  |  in/out words (W each) + n_c W masks + 12 sign/nonzero
+#                planes + base + in/out LFSR columns (one per lane)
+# plus the halo faces, widened to 32-bit (x faces as planes, y/z faces as
+# one (1, n) row per x-plane).  Threshold rows and betas live in SMEM.
+# ``tiled=False`` gives the unpadded byte count — what a jaxpr sees, and
+# what the static auditor (rule IR-F) compares against.
 DEFAULT_VMEM_BUDGET = 16 << 20  # 16 MiB/core, the TPU VMEM working budget
+_LANES = 128
 
 
-def _per_site_bytes(precision: str, n_colors: int,
-                    lanes: int = LANE_WIDTH) -> int:
+def _round_up(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
+
+
+def _plane_bytes(by: int, bz: int, itemsize: int, tiled: bool = True) -> int:
+    """VMEM bytes of one (by, bz) plane of ``itemsize``-byte elements."""
+    if not tiled:
+        return by * bz * itemsize
+    return (_round_up(by, 8 * (4 // itemsize)) * _round_up(bz, _LANES)
+            * itemsize)
+
+
+def _fused_planes(precision: str, n_colors: int,
+                  lanes: int = LANE_WIDTH) -> Tuple[int, int]:
+    """(int8, 32-bit) brick-shaped buffers of the single-block kernel."""
     if precision == "bitplane":
-        # W stacked word planes: in/out spin words and color masks scale
-        # with W, the 12+1 sign/nonzero/base planes are shared by every
-        # word, LFSR columns are per lane.  W=1 reduces to the PR 4 value
-        # 60 + 4 n_c + 8 lanes.
         words = max(1, (int(lanes) + LANE_WIDTH - 1) // LANE_WIDTH)
-        return 52 + 8 * words + 4 * n_colors * words + 8 * lanes
-    return _PER_SITE_BYTES[precision] + n_colors
+        return 0, 2 * words + n_colors * words + 13 + 2 * int(lanes)
+    if precision == "int8":
+        return n_colors + 9, 2
+    return n_colors + 2, 9
+
+
+def _halo_bytes(brick, bx: int, tiled: bool) -> int:
+    """Two x-face planes plus 2+2 y/z face rows for ``bx`` x-planes."""
+    _, by, bz = brick
+    if not tiled:
+        return 2 * 4 * (by * bz + bx * bz + bx * by)
+    return (2 * _plane_bytes(by, bz, 4)
+            + 2 * bx * (_plane_bytes(1, bz, 4) + _plane_bytes(1, by, 4)))
 
 
 def fused_working_set_bytes(brick: Tuple[int, int, int], n_colors: int,
                             precision: str = "f32",
-                            lut_width: Optional[int] = None,
-                            lanes: int = LANE_WIDTH) -> int:
+                            lanes: int = LANE_WIDTH,
+                            tiled: bool = True) -> int:
     """VMEM bytes the single-block fused sweep kernel needs for one brick.
 
     ``lanes`` only matters on the bitplane path (per-lane LFSR columns)."""
     bx, by, bz = brick
-    sites = bx * by * bz
-    per_site = _per_site_bytes(precision, n_colors, lanes)
-    halo_unit = 4 if precision == "bitplane" else 1   # word vs int8 planes
-    halo = 2 * halo_unit * (by * bz + bx * bz + bx * by)
-    lut = 0
-    if precision in ("int8", "bitplane"):
-        lut = 4 * _LUT_ROWS_NOMINAL * (lut_width if lut_width else 1)
-    return per_site * sites + halo + lut
+    n8, n32 = _fused_planes(precision, n_colors, lanes)
+    plane = (n8 * _plane_bytes(by, bz, 1, tiled)
+             + n32 * _plane_bytes(by, bz, 4, tiled))
+    return bx * plane + _halo_bytes(brick, bx, tiled)
+
+
+def tiled_working_set_bytes(brick: Tuple[int, int, int], bx: int,
+                            kernel: str = "int8") -> int:
+    """VMEM bytes of one x-tiled kernel launch with x-slabs of ``bx``
+    planes: the per-phase update (``kernel`` "f32" / "int8") or the energy
+    readout ("energy").  The grid double-buffers every block; the planes
+    just outside the slab arrive as one-plane blocks."""
+    _, by, bz = brick
+    # (int8 planes, 32-bit planes) per x-plane of the slab
+    n8, n32 = {"f32": (3, 9), "int8": (10, 2), "energy": (2, 7)}[kernel]
+    slab = bx * (n8 * _plane_bytes(by, bz, 1) + n32 * _plane_bytes(by, bz, 4))
+    edges = 2 * _plane_bytes(by, bz, 1)
+    return 2 * (slab + edges + _halo_bytes(brick, bx, True))
+
+
+def pick_x_tile(brick: Tuple[int, int, int], kernel: str,
+                budget: int = DEFAULT_VMEM_BUDGET) -> Optional[int]:
+    """Largest divisor of the brick's x extent whose tiled working set
+    fits ``budget`` (None if even one plane does not)."""
+    Bx = int(brick[0])
+    fits = [d for d in range(1, Bx + 1) if Bx % d == 0
+            and tiled_working_set_bytes(brick, d, kernel) <= budget]
+    return max(fits) if fits else None
 
 
 def fused_brick_ceiling(n_colors: int, precision: str = "f32",
                         budget: int = DEFAULT_VMEM_BUDGET,
                         lanes: int = LANE_WIDTH) -> int:
     """Largest cubic brick extent whose fused working set fits ``budget``."""
-    per_site = _per_site_bytes(precision, n_colors, lanes)
-    side = int(round((budget / per_site) ** (1.0 / 3.0)))
-    while fused_working_set_bytes((side, side, side), n_colors,
-                                  precision, lanes=lanes) > budget:
+    n8, n32 = _fused_planes(precision, n_colors, lanes)
+    side = int((budget / (n8 + 4 * n32)) ** (1.0 / 3.0)) + 1
+    while side > 0 and fused_working_set_bytes(
+            (side, side, side), n_colors, precision, lanes=lanes) > budget:
         side -= 1
     return side
 
@@ -193,9 +238,10 @@ class LatticeDSIM:
                                                                    prob.w6)
             self.f_max = field_bound(self.h_q, self.w6_q)
             # Mosaic cannot gather per element from VMEM: the Pallas int
-            # kernels rely on lut_accept's rank-count form, which caps the
-            # row width.  The bitplane path uses the rank count on EVERY
-            # impl (the word math has no per-lane gather form at all).
+            # kernels evaluate the accept as a rank count over threshold
+            # rows held in SMEM, which caps the row width.  The bitplane
+            # path uses the rank count on EVERY impl (the word math has no
+            # per-lane gather form at all).
             # Fail at init with a clear message, not at first lowering.
             from repro.kernels.ops import default_impl
             resolved = impl if impl != "auto" else default_impl()
@@ -237,51 +283,60 @@ class LatticeDSIM:
         self.brick = tuple(e // k for e, k in zip(prob.dims, self.nb))
         # fused-vs-per-phase decision (DESIGN.md "VMEM working-set math"):
         # x-tiling forces per-phase; so does a brick working set beyond the
-        # VMEM budget — the fallback is no longer silent.  The bitplane
-        # path has exactly one dispatch (the single-block word kernel), so
-        # an over-budget brick warns but cannot fall back.
+        # VMEM budget, and the fallback then picks the largest x-tile whose
+        # double-buffered slab fits.  The bitplane path has exactly one
+        # dispatch (the single-block word kernel), so an over-budget brick
+        # is refused.
         self.fused_requested = bool(fused)
         # bitplane launches are per WORD PLANE, so the kernel working set
         # is bounded by one full word (<= 32 lanes) regardless of W
         launch_lanes = min(self.replicas, LANE_WIDTH) \
             if precision == "bitplane" else self.replicas
         self.fused_working_set = fused_working_set_bytes(
-            self.brick, prob.n_colors, precision,
-            lut_width=2 * self.f_max + 1, lanes=launch_lanes)
+            self.brick, prob.n_colors, precision, lanes=launch_lanes)
         self.fallback_reason = None
         fused = bool(fused)
+        budget = self.vmem_budget_bytes
         if precision == "bitplane":
-            if self.fused_working_set > self.vmem_budget_bytes:
+            if self.fused_working_set > budget:
                 ceiling = fused_brick_ceiling(prob.n_colors, precision,
-                                              self.vmem_budget_bytes,
-                                              lanes=launch_lanes)
-                warnings.warn(
+                                              budget, lanes=launch_lanes)
+                raise ValueError(
                     f"bitplane sweep kernel needs "
                     f"{self.fused_working_set:,} B of VMEM for brick "
                     f"{self.brick} ({launch_lanes} lanes per word-plane "
-                    f"launch, {prob.n_colors} colors) — over the "
-                    f"{self.vmem_budget_bytes:,} B budget and the word "
-                    f"kernel has no per-phase fallback; shard to bricks of "
-                    f"~{ceiling}^3 or fewer sites for TPU.",
-                    RuntimeWarning, stacklevel=2)
+                    f"launch, {prob.n_colors} colors), over the "
+                    f"{budget:,} B budget, and the word kernel has no "
+                    f"per-phase fallback: its ceiling is a {ceiling}^3 "
+                    f"brick.  Shard over more devices or use "
+                    f"precision='int8'.")
             self.fused = True
         else:
             if fused and kernel_bx is not None:
                 fused, self.fallback_reason = False, "kernel_bx"
-            if fused and self.fused_working_set > self.vmem_budget_bytes:
+            if fused and self.fused_working_set > budget:
                 ceiling = fused_brick_ceiling(prob.n_colors, precision,
-                                              self.vmem_budget_bytes)
+                                              budget)
                 fused, self.fallback_reason = False, "vmem"
+                self.kernel_bx = pick_x_tile(self.brick, precision, budget)
+                if self.kernel_bx is None:
+                    raise ValueError(
+                        f"no x-tile of brick {self.brick} fits the "
+                        f"{budget:,} B VMEM budget ({precision} per-phase "
+                        f"kernel); shard over more devices")
                 warnings.warn(
                     f"lattice fused sweep kernel needs "
                     f"{self.fused_working_set:,} B of VMEM for brick "
                     f"{self.brick} ({precision}, {prob.n_colors} colors) — "
-                    f"over the {self.vmem_budget_bytes:,} B budget; falling "
-                    f"back to the per-phase x-tiled dispatch.  Fused "
-                    f"single-block ceiling at this budget is ~{ceiling}^3 "
-                    f"per brick.",
+                    f"over the {budget:,} B budget; falling back to the "
+                    f"per-phase dispatch with x-tiles of {self.kernel_bx} "
+                    f"planes.  Fused single-block ceiling at this budget "
+                    f"is ~{ceiling}^3 per brick.",
                     RuntimeWarning, stacklevel=2)
             self.fused = fused
+        # the energy readout is x-tiled the same way (its f32 couplings
+        # make it the widest per-plane kernel); one plane is the floor
+        self.energy_bx = pick_x_tile(self.brick, "energy", budget) or 1
         ax, ay, az = dim_axes
         self.spec_m = P(None, ax, ay, az)        # leading replica axis
         self.spec_flat = P(ax, ay, az)           # problem constants (no R)
@@ -1090,7 +1145,7 @@ class LatticeDSIM:
                     halos = self._exchange_block(m)
                 e = jax.vmap(
                     lambda mr, hr: brick_energy_op(mr, active, h, w6, hr,
-                                                   bx=self.kernel_bx,
+                                                   bx=self.energy_bx,
                                                    impl=self.impl),
                     in_axes=(0, 0))(m, halos)
                 return jax.lax.psum(e, axes_all) if axes_all else e

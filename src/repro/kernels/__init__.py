@@ -8,5 +8,6 @@ ops            — jit'd dispatch (pallas on TPU / interpret for validation /
                  jnp ref on CPU); ref — pure-jnp oracles.
 
 Validated in interpret mode against the oracles across shape/format sweeps
-(bitwise-equal spins and LFSR states; allclose energies).
+(bitwise-equal spins and LFSR states; allclose energies), and compiled for
+a described TPU v5e at real brick sizes by tests/test_tpu_compile.py.
 """

@@ -6,7 +6,7 @@ Shadow (cross-device) couplings are halved correctly because both sides hold
 a copy: summing -1/2 m_i J_ij m_j over both devices yields each cut edge
 exactly once after the global psum.
 
-Grid steps accumulate into a single (1, 1) output block — the standard
+Grid steps (x-slabs) accumulate into one (1, 1) SMEM scalar — the standard
 Pallas reduction idiom (output index map constant, init at step 0).
 """
 
@@ -19,58 +19,64 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .pbit_lattice import (SMEM, eye_mask, kernel_halos, plane_neighbors,
+                           sweep_planes)
+
 __all__ = ["brick_energy"]
 
 
 def _kernel(active_ref, h_ref, wxm_ref, wxp_ref, wym_ref, wyp_ref,
-            wzm_ref, wzp_ref, m_l_ref, m_c_ref, m_r_ref,
+            wzm_ref, wzp_ref, m_l_ref, m_ref, m_r_ref,
             xlo_ref, xhi_ref, ylo_ref, yhi_ref, zlo_ref, zhi_ref,
             out_ref, *, nblocks: int):
     i = pl.program_id(0)
-    f32 = jnp.float32
-    mc = m_c_ref[...].astype(f32)
-    left = jnp.where(i == 0, xlo_ref[...].astype(f32)[None],
-                     m_l_ref[...][-1:].astype(f32))
-    right = jnp.where(i == nblocks - 1, xhi_ref[...].astype(f32)[None],
-                      m_r_ref[...][:1].astype(f32))
-    xm = jnp.concatenate([left, mc[:-1]], axis=0)
-    xp = jnp.concatenate([mc[1:], right], axis=0)
-    ym = jnp.concatenate([ylo_ref[...].astype(f32)[:, None, :], mc[:, :-1]], axis=1)
-    yp = jnp.concatenate([mc[:, 1:], yhi_ref[...].astype(f32)[:, None, :]], axis=1)
-    zm = jnp.concatenate([zlo_ref[...].astype(f32)[:, :, None], mc[:, :, :-1]], axis=2)
-    zp = jnp.concatenate([mc[:, :, 1:], zhi_ref[...].astype(f32)[:, :, None]], axis=2)
+    f32, i32 = jnp.float32, jnp.int32
+    bx, By, Bz = m_ref.shape
+    eye = eye_mask(By)
+    w_refs = (wxm_ref, wxp_ref, wym_ref, wyp_ref, wzm_ref, wzp_ref)
+    first_prev = jnp.where(i == 0, xlo_ref[...], m_l_ref[0].astype(i32))
+    last_next = jnp.where(i == nblocks - 1, xhi_ref[...],
+                          m_r_ref[0].astype(i32))
 
-    pair = (wxm_ref[...] * xm + wxp_ref[...] * xp
-            + wym_ref[...] * ym + wyp_ref[...] * yp
-            + wzm_ref[...] * zm + wzp_ref[...] * zp)
-    act = active_ref[...].astype(f32)
-    e = (-0.5 * (mc * pair) - h_ref[...] * mc) * act
+    def step(x, prev, cur, nxt, acc):
+        nbs = plane_neighbors(prev, cur, nxt, ylo_ref[x], yhi_ref[x],
+                              zlo_ref[x], zhi_ref[x], eye)
+        pair = w_refs[0][x] * nbs[0].astype(f32)
+        for w, nb in zip(w_refs[1:], nbs[1:]):
+            pair = pair + w[x] * nb.astype(f32)
+        mc = cur.astype(f32)
+        act = active_ref[x].astype(f32)
+        return acc + (-0.5 * (mc * pair) - h_ref[x] * mc) * act
+
+    e = sweep_planes(lambda x: m_ref[x].astype(i32), bx, first_prev,
+                     last_next, step, jnp.zeros((By, Bz), f32))
 
     @pl.when(i == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[0, 0] = jnp.float32(0)
 
-    out_ref[0, 0] += e.sum()
+    out_ref[0, 0] += jnp.sum(e)
 
 
 @functools.partial(jax.jit, static_argnames=("bx", "interpret"))
 def brick_energy(m, active, h, w6, halos, bx: Optional[int] = None,
-                 interpret: bool = True):
-    """Brick-local Ising energy (psum across bricks gives the global E)."""
+                 interpret: bool = False):
+    """Brick-local Ising energy (psum across bricks gives the global E).
+
+    ``bx`` tiles x (any divisor of Bx; default the whole brick)."""
     Bx, By, Bz = m.shape
     bx = Bx if bx is None else bx
     if Bx % bx != 0:
         raise ValueError(f"Bx={Bx} not divisible by tile bx={bx}")
     nb = Bx // bx
-    wxm, wxp, wym, wyp, wzm, wzp = w6
-    xlo, xhi, ylo, yhi, zlo, zhi = halos
 
-    blk = (bx, By, Bz)
-    cur = pl.BlockSpec(blk, lambda i: (i, 0, 0))
-    prv = pl.BlockSpec(blk, lambda i: (jnp.maximum(i - 1, 0), 0, 0))
-    nxt = pl.BlockSpec(blk, lambda i: (jnp.minimum(i + 1, nb - 1), 0, 0))
-    full2 = lambda a, b: pl.BlockSpec((a, b), lambda i: (0, 0))
-    xtile = lambda b2: pl.BlockSpec((bx, b2), lambda i: (i, 0))
+    cur = pl.BlockSpec((bx, By, Bz), lambda i: (i, 0, 0))
+    prv = pl.BlockSpec((1, By, Bz), lambda i: (jnp.maximum(i * bx - 1, 0),
+                                               0, 0))
+    nxt = pl.BlockSpec((1, By, Bz), lambda i: (jnp.minimum((i + 1) * bx,
+                                                           Bx - 1), 0, 0))
+    face_x = pl.BlockSpec((By, Bz), lambda i: (0, 0))
+    row = lambda n: pl.BlockSpec((bx, 1, n), lambda i: (i, 0, 0))  # noqa: E731
 
     out = pl.pallas_call(
         functools.partial(_kernel, nblocks=nb),
@@ -78,13 +84,10 @@ def brick_energy(m, active, h, w6, halos, bx: Optional[int] = None,
         in_specs=[
             cur, cur, cur, cur, cur, cur, cur, cur,
             prv, cur, nxt,
-            full2(By, Bz), full2(By, Bz),
-            xtile(Bz), xtile(Bz),
-            xtile(By), xtile(By),
+            face_x, face_x, row(Bz), row(Bz), row(By), row(By),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        out_specs=SMEM,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
-    )(active, h, wxm, wxp, wym, wyp, wzm, wzp, m, m, m,
-      xlo, xhi, ylo, yhi, zlo, zhi)
+    )(active, h, *w6, m, m, m, *kernel_halos(halos, jnp.int32))
     return out[0, 0]

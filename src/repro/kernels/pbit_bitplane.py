@@ -32,9 +32,11 @@ VMEM working set for a (Bx, By, Bz) brick of R lanes:
   in/out LFSR columns (u32, R lanes)      8R B/site
   6 sign + 6 nonzero planes (u32)         48 B/site
   base (i32) + n_c color masks (u32)      (4 + 4 n_c) B/site
-~= (60 + 4 n_c + 8 R) B/site — ~328 B/site at R=32, n_c=3, i.e. ~10.3
-B/site/replica-lane (vs the int8 path's 17 + n_c) and ONE launch where the
-int8 path needs R.
+~= (60 + 4 n_c + 8 R) B/site before tile padding — ~328 B/site at R=32,
+n_c=3, i.e. ~10.3 B/site/replica-lane (vs the int8 path's 17 + n_c) and
+ONE launch where the int8 path needs R.  The brick is walked one x-plane
+at a time, like the int8 kernels (``kernels.pbit_lattice``); each sweep's
+per-lane threshold rows are gathered in XLA and read from SMEM.
 """
 
 from __future__ import annotations
@@ -45,10 +47,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .pbit_lattice import (SMEM, VMEM, eye_mask, kernel_halos, lfsr_draw,
+                           plane_neighbors, rank_accept, sweep_planes)
+
 __all__ = ["pbit_bitplane_sweep"]
 
 
-def _bitplane_kernel(rows_ref, lut_ref, masks_ref,
+def _bitplane_kernel(thr_ref, masks_ref,
                      sxm_ref, sxp_ref, sym_ref, syp_ref, szm_ref, szp_ref,
                      nxm_ref, nxp_ref, nym_ref, nyp_ref, nzm_ref, nzp_ref,
                      base_ref, m_ref,
@@ -60,80 +65,65 @@ def _bitplane_kernel(rows_ref, lut_ref, masks_ref,
     i32 = jnp.int32
     u32 = jnp.uint32
     one = u32(1)
-    mw = m_ref[...]
-    base = base_ref[...]
-    signs = (sxm_ref[...], sxp_ref[...], sym_ref[...],
-             syp_ref[...], szm_ref[...], szp_ref[...])
-    nzs = (nxm_ref[...], nxp_ref[...], nym_ref[...],
-           nyp_ref[...], nzm_ref[...], nzp_ref[...])
-    xlo = xlo_ref[...][None]
-    xhi = xhi_ref[...][None]
-    ylo = ylo_ref[...][:, None, :]
-    yhi = yhi_ref[...][:, None, :]
-    zlo = zlo_ref[...][:, :, None]
-    zhi = zhi_ref[...][:, :, None]
-    lut = lut_ref[...]
-    # per-lane LFSR columns carried in registers across every phase
-    lfsr = [s_ref[r] for r in range(n_lanes)]
-    flips = [jnp.zeros((), i32) for _ in range(n_lanes)]
-
-    for t in range(n_sweeps):                     # static unroll: S is small
-        for c in range(n_colors):
-            xm = jnp.concatenate([xlo, mw[:-1]], axis=0)
-            xp = jnp.concatenate([mw[1:], xhi], axis=0)
-            ym = jnp.concatenate([ylo, mw[:, :-1]], axis=1)
-            yp = jnp.concatenate([mw[:, 1:], yhi], axis=1)
-            zm = jnp.concatenate([zlo, mw[:, :, :-1]], axis=2)
-            zp = jnp.concatenate([mw[:, :, 1:], zhi], axis=2)
-            tb = [(nb ^ sg) & nz for nb, sg, nz in
-                  zip((xm, xp, ym, yp, zm, zp), signs, nzs)]
-            # carry-save adder tree: c = b0 + 2 b1 + 4 b2, all 32 lanes
-            s1 = tb[0] ^ tb[1] ^ tb[2]
-            c1 = (tb[0] & tb[1]) | (tb[2] & (tb[0] ^ tb[1]))
-            s2 = tb[3] ^ tb[4] ^ tb[5]
-            c2 = (tb[3] & tb[4]) | (tb[5] & (tb[3] ^ tb[4]))
-            b0 = s1 ^ s2
-            k = s1 & s2
-            b1 = c1 ^ c2 ^ k
-            b2 = (c1 & c2) | (k & (c1 ^ c2))
-
-            upd = jnp.zeros(mw.shape, u32)
-            for r in range(n_lanes):              # per-lane RNG + accept
-                s = lfsr[r]
-                s = s ^ (s << u32(13))
-                s = s ^ (s >> u32(17))
-                s = s ^ (s << u32(5))
-                lfsr[r] = s
-                u = s >> u32(8)
-                thr = jax.lax.dynamic_index_in_dim(
-                    lut, rows_ref[t, r], axis=0, keepdims=False)
-                ur = u32(r)
-                cnt = (((b0 >> ur) & one).astype(i32)
-                       + 2 * ((b1 >> ur) & one).astype(i32)
-                       + 4 * ((b2 >> ur) & one).astype(i32))
-                idx = jnp.clip(base + 2 * cnt, 0, lut_width - 1)
-                count = jnp.zeros(u.shape, i32)
-                for q in range(lut_width):        # rank-count accept
-                    count = count + (u >= thr[q]).astype(i32)
-                accept = idx + count >= lut_width
-                upd = upd | (accept.astype(u32) << ur)
-
-            new = (mw & ~masks_ref[c]) | (upd & masks_ref[c])
-            diff = mw ^ new
-            for r in range(n_lanes):
-                flips[r] = flips[r] + ((diff >> u32(r)) & one) \
-                    .astype(i32).sum()
-            mw = new
-
-    m_out_ref[...] = mw
+    Bx, By, Bz = m_ref.shape
+    eye = eye_mask(By)
+    sign_refs = (sxm_ref, sxp_ref, sym_ref, syp_ref, szm_ref, szp_ref)
+    nz_refs = (nxm_ref, nxp_ref, nym_ref, nyp_ref, nzm_ref, nzp_ref)
+    m_out_ref[...] = m_ref[...]
+    s_out_ref[...] = s_ref[...]
     for r in range(n_lanes):
-        s_out_ref[r] = lfsr[r]
-        flips_ref[r, 0] = flips[r]
+        flips_ref[r] = i32(0)
+    xlo, xhi = xlo_ref[...], xhi_ref[...]
+
+    def sweep(t, carry):
+        for c in range(n_colors):
+            def step(x, prev, cur, nxt, acc, c=c):
+                nbs = plane_neighbors(prev, cur, nxt, ylo_ref[x], yhi_ref[x],
+                                      zlo_ref[x], zhi_ref[x], eye)
+                tb = [(nb ^ sg[x]) & nz[x] for nb, sg, nz in
+                      zip(nbs, sign_refs, nz_refs)]
+                # carry-save adder tree: c = b0 + 2 b1 + 4 b2, all 32 lanes
+                s1 = tb[0] ^ tb[1] ^ tb[2]
+                c1 = (tb[0] & tb[1]) | (tb[2] & (tb[0] ^ tb[1]))
+                s2 = tb[3] ^ tb[4] ^ tb[5]
+                c2 = (tb[3] & tb[4]) | (tb[5] & (tb[3] ^ tb[4]))
+                b0 = s1 ^ s2
+                k = s1 & s2
+                b1 = c1 ^ c2 ^ k
+                b2 = (c1 & c2) | (k & (c1 ^ c2))
+                base = base_ref[x]
+
+                upd = jnp.zeros(cur.shape, u32)
+                for r in range(n_lanes):          # per-lane RNG + accept
+                    s, u = lfsr_draw(s_out_ref[r, x])
+                    s_out_ref[r, x] = s
+                    ur = u32(r)
+                    cnt = (((b0 >> ur) & one).astype(i32)
+                           + 2 * ((b1 >> ur) & one).astype(i32)
+                           + 4 * ((b2 >> ur) & one).astype(i32))
+                    idx = jnp.clip(base + 2 * cnt, 0, lut_width - 1)
+                    accept = rank_accept(u, idx, thr_ref,
+                                         (t * n_lanes + r) * lut_width,
+                                         lut_width)
+                    upd = upd | (accept.astype(u32) << ur)
+
+                mask = masks_ref[c, x]
+                new = (cur & ~mask) | (upd & mask)
+                m_out_ref[x] = new
+                diff = cur ^ new
+                for r in range(n_lanes):
+                    flips_ref[r] += jnp.sum(((diff >> u32(r)) & one)
+                                            .astype(i32))
+                return acc
+            sweep_planes(lambda x: m_out_ref[x], Bx, xlo, xhi, step, i32(0))
+        return carry
+
+    jax.lax.fori_loop(0, n_sweeps, sweep, i32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pbit_bitplane_sweep(mw, s, rows, masks_w, signs6, nz6, base, halos_w,
-                        lut, interpret: bool = True):
+                        lut, interpret: bool = False):
     """``rows.shape[0]`` fused multi-spin-coded sweeps of one brick.
 
     Args match :func:`repro.kernels.ref.pbit_bitplane_sweep_ref` (rows must
@@ -144,40 +134,20 @@ def pbit_bitplane_sweep(mw, s, rows, masks_w, signs6, nz6, base, halos_w,
     R = int(s.shape[0])
     S = int(rows.shape[0])
     n_colors = int(masks_w.shape[0])
-    n_rows, lw = lut.shape
-    sxm, sxp, sym, syp, szm, szp = signs6
-    nxm, nxp, nym, nyp, nzm, nzp = nz6
-    xlo, xhi, ylo, yhi, zlo, zhi = halos_w
+    lw = int(lut.shape[1])
     rows = jnp.asarray(rows, jnp.int32).reshape(S, R)
+    thr = lut[rows].astype(jnp.int32).reshape(-1)     # (S*R*lw,) for SMEM
 
-    whole = pl.BlockSpec((Bx, By, Bz), lambda: (0, 0, 0))
-    full = lambda *sh: pl.BlockSpec(sh, lambda: (0,) * len(sh))
-
-    m_new, s_new, flips = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bitplane_kernel, n_colors=n_colors, n_sweeps=S,
                           n_lanes=R, lut_width=lw),
-        grid=(),
-        in_specs=[
-            full(S, R),                           # LUT row per (sweep, lane)
-            full(n_rows, lw),                     # threshold LUT
-            full(n_colors, Bx, By, Bz),           # lane-masked color masks
-            whole, whole, whole, whole, whole, whole,   # 6 sign planes
-            whole, whole, whole, whole, whole, whole,   # 6 nonzero planes
-            whole,                                # base (i32)
-            whole,                                # spin words
-            full(By, Bz), full(By, Bz),           # xlo, xhi
-            full(Bx, Bz), full(Bx, Bz),           # ylo, yhi
-            full(Bx, By), full(Bx, By),           # zlo, zhi
-            full(R, Bx, By, Bz),                  # LFSR columns
-        ],
-        out_specs=[whole, full(R, Bx, By, Bz), full(R, 1)],
+        in_specs=[SMEM] + [VMEM] * 22,
+        out_specs=[VMEM, VMEM, SMEM],
         out_shape=[
             jax.ShapeDtypeStruct((Bx, By, Bz), jnp.uint32),
             jax.ShapeDtypeStruct((R, Bx, By, Bz), jnp.uint32),
-            jax.ShapeDtypeStruct((R, 1), jnp.int32),
+            jax.ShapeDtypeStruct((R,), jnp.int32),
         ],
         interpret=interpret,
-    )(rows, lut, masks_w, sxm, sxp, sym, syp, szm, szp,
-      nxm, nxp, nym, nyp, nzm, nzp, base, mw,
-      xlo, xhi, ylo, yhi, zlo, zhi, s)
-    return m_new, s_new, flips[:, 0]
+    )(thr, masks_w, *signs6, *nz6, base, mw,
+      *kernel_halos(halos_w, jnp.uint32), s)

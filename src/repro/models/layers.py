@@ -152,13 +152,7 @@ def shard_hint(x, *spec):
         clean.append(P.UNCONSTRAINED)
     if not used:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*clean))
-    except Exception:
-        from repro.compat import HAS_NEW_SHARDING
-        if HAS_NEW_SHARDING:
-            raise  # real spec/mesh bug — don't mask it on modern jax
-        return x   # legacy jax: constraint unsupported in this context
+    return jax.lax.with_sharding_constraint(x, P(*clean))
 
 
 BATCH_AXES = ("pod", "data")

@@ -9,12 +9,12 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analyze.findings import Finding, Waivers, render_report
 from repro.analyze.ir_rules import ChunkAudit, audit_chunk
 from repro.analyze.lint import lint_file
-from repro.compat import shard_map
+from repro.compat import abstract_mesh, shard_map
 
 U32 = (np.dtype(np.uint32),)
 
@@ -46,7 +46,7 @@ def test_ir_a_catches_float_arith_in_int8_body():
 
 
 def test_ir_b_catches_8bit_wire_in_bitplane_chunk():
-    mesh = AbstractMesh((("data", 2),))
+    mesh = abstract_mesh((2,), ("data",))
 
     def body(x):
         return jax.lax.all_gather(x, "data", tiled=True)
@@ -62,7 +62,7 @@ def test_ir_b_catches_8bit_wire_in_bitplane_chunk():
 
 
 def test_ir_b_catches_payload_byte_mismatch():
-    mesh = AbstractMesh((("data", 2),))
+    mesh = abstract_mesh((2,), ("data",))
 
     def body(x):
         return jax.lax.all_gather(x, "data", tiled=True)
@@ -79,7 +79,7 @@ def test_ir_b_catches_payload_byte_mismatch():
 
 
 def test_ir_c_catches_collective_count_mismatch():
-    mesh = AbstractMesh((("data", 2),))
+    mesh = abstract_mesh((2,), ("data",))
 
     def body(x):
         return jax.lax.psum(x, "data")
@@ -98,7 +98,7 @@ def test_ir_c_catches_collective_count_mismatch():
 
 
 def test_ir_c_scales_counts_by_scan_length():
-    mesh = AbstractMesh((("data", 2),))
+    mesh = abstract_mesh((2,), ("data",))
 
     def body(x):
         def step(c, _):
@@ -149,7 +149,7 @@ def test_ir_e_checks_seq_dtype():
 
 
 def test_ir_f_catches_working_set_drift():
-    mesh = AbstractMesh((("data", 2),))
+    mesh = abstract_mesh((2,), ("data",))
 
     def body(x):
         return x + jnp.float32(1)

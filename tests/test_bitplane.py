@@ -354,19 +354,27 @@ def test_bitplane_working_set_per_lane_beats_int8():
     for n_c in (2, 3):
         per_lane_bp = fused_working_set_bytes(b, n_c, "bitplane",
                                               lanes=32) / 32
-        per_rep_i8 = fused_working_set_bytes(b, n_c, "int8", lut_width=13)
+        per_rep_i8 = fused_working_set_bytes(b, n_c, "int8")
         assert per_lane_bp < per_rep_i8
-    assert fused_brick_ceiling(3, "bitplane", lanes=32) >= 32
+    # z rides the 128 lanes, so small bricks pay lane padding: 16^3 at R=32
+    assert fused_brick_ceiling(3, "bitplane", lanes=32) >= 16
 
 
-def test_bitplane_over_budget_warns_not_falls_back():
+def test_bitplane_over_ceiling_raises():
+    """The word kernel has no per-phase fallback: a brick over its VMEM
+    ceiling is refused at construction, naming the ceiling."""
     prob = build_ea3d_lattice(6, seed=0)
     mesh = make_mesh((1,), ("data",), axis_types=auto_axes(1))
-    with pytest.warns(RuntimeWarning, match="no per-phase fallback"):
-        eng = LatticeDSIM(prob, mesh, dim_axes=("data", None, None),
-                          precision="bitplane", impl="ref", replicas=32,
-                          vmem_budget_bytes=1024)
-    assert eng.kernel_path == "bitplane"    # still the word kernel
+    ceiling = fused_brick_ceiling(prob.n_colors, "bitplane", 1 << 20,
+                                  lanes=32)
+    assert ceiling < 6
+    with pytest.raises(ValueError, match=rf"ceiling is a {ceiling}\^3"):
+        LatticeDSIM(prob, mesh, dim_axes=("data", None, None),
+                    precision="bitplane", impl="ref", replicas=32,
+                    vmem_budget_bytes=1 << 20)
+    eng = LatticeDSIM(prob, mesh, dim_axes=("data", None, None),
+                      precision="bitplane", impl="ref", replicas=32)
+    assert eng.kernel_path == "bitplane"
     st = eng.init_state(seed=0)
     st, rec = eng.run_recorded(st, ea_schedule(8), [8], sync_every=4)
     assert float(np.asarray(rec.energies[-1]).min()) < 0

@@ -386,7 +386,7 @@ def test_degrade_zero_fault_parity_2dev():
 
         L = 4
         sch = ea_schedule(40)
-        mesh = make_mesh((2,), ("data",), axis_types=auto_axes(2))
+        mesh = make_mesh((2,), ("data",), axis_types=auto_axes(1))
         g = ea3d(L, seed=7)
         dprob = build_partitioned(g, lattice3d_coloring(L),
                                   slab_partition(L, 2), 2)
@@ -445,7 +445,7 @@ def test_dsim_dist_poisoned_exchange_2dev():
         g = ea3d(L, seed=7)
         prob = build_partitioned(g, lattice3d_coloring(L),
                                  slab_partition(L, 2), 2)
-        mesh = make_mesh((2,), ("data",), axis_types=auto_axes(2))
+        mesh = make_mesh((2,), ("data",), axis_types=auto_axes(1))
         sch = ea_schedule(40)   # 40 sweeps, sync 4 -> 10 exchanges
 
         def run(prec, degrade=None, codes=None):
@@ -511,7 +511,7 @@ def test_lattice_poisoned_exchange_2dev():
         from repro.compat import make_mesh, auto_axes
 
         prob = build_ea3d_lattice(4, seed=7)
-        mesh = make_mesh((2,), ("data",), axis_types=auto_axes(2))
+        mesh = make_mesh((2,), ("data",), axis_types=auto_axes(1))
         sch = ea_schedule(40)
 
         def run(prec, degrade=None, codes=None):

@@ -253,7 +253,7 @@ def test_eta_meter_2device_dsim_dist():
         h = make_engine("dsim_dist", g, coloring=lattice3d_coloring(L),
                         K=2, labels=slab_partition(L, 2),
                         mesh=make_mesh((2,), ("data",),
-                                       axis_types=auto_axes(2)),
+                                       axis_types=auto_axes(1)),
                         rng="lfsr", replicas=4)
         meter = dist_eta_meter(h.eng, sync_every=8)
         sch = constant_schedule(3.0, 8 * 64)

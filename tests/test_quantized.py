@@ -24,8 +24,10 @@ from repro.core.annealing import (ArraySchedule, beta_table,
                                   beta_row_indices, ea_schedule,
                                   replica_beta_arrays)
 from repro.core.lattice import build_ea3d_lattice
-from repro.core.lattice_dsim import (LatticeDSIM, fused_brick_ceiling,
-                                     fused_working_set_bytes)
+from repro.core.lattice_dsim import (DEFAULT_VMEM_BUDGET, LatticeDSIM,
+                                     fused_brick_ceiling,
+                                     fused_working_set_bytes, pick_x_tile,
+                                     tiled_working_set_bytes)
 from repro.compat import make_mesh, auto_axes
 from repro.engines import make_engine
 from repro.kernels.ops import pbit_update_int_op, pbit_sweep_int_op
@@ -291,12 +293,14 @@ def test_per_replica_staircase_rides_int8_path():
 def test_fused_fallback_warns_and_is_exposed():
     prob = build_ea3d_lattice(6, seed=0)
     mesh = make_mesh((1,), ("data",), axis_types=auto_axes(1))
+    budget = 1 << 18          # below the fused 6^3 f32 brick, above a slab
     with pytest.warns(RuntimeWarning, match="falling back"):
         eng = LatticeDSIM(prob, mesh, dim_axes=("data", None, None),
-                          impl="ref", vmem_budget_bytes=1024)
+                          impl="ref", vmem_budget_bytes=budget)
     assert eng.kernel_path == "per_phase"
     assert eng.fallback_reason == "vmem"
     assert eng.fused_requested and not eng.fused
+    assert tiled_working_set_bytes(eng.brick, eng.kernel_bx, "f32") <= budget
     # the fallback engine still runs (per-phase dispatch)
     st = eng.init_state(seed=0)
     st, rec = eng.run_recorded(st, ea_schedule(8), [8], sync_every=4)
@@ -310,7 +314,7 @@ def test_fused_decision_default_budget_and_handle_exposure():
     assert h.kernel_path == "fused"
     assert h.precision == "f32"
     h2 = make_engine("lattice", L=6, seed=0, impl="ref", precision="int8",
-                     vmem_budget_bytes=1 << 14)  # 16 KiB: 6^3 int8 fits
+                     vmem_budget_bytes=1 << 19)  # 512 KiB: 6^3 int8 fits
     assert h2.kernel_path == "fused" and h2.precision == "int8"
 
 
@@ -320,10 +324,41 @@ def test_int8_raises_fused_brick_ceiling():
     for n_c in (2, 3):
         assert fused_brick_ceiling(n_c, "int8") > fused_brick_ceiling(n_c,
                                                                       "f32")
-    assert fused_brick_ceiling(2, "int8") >= 90      # the ~96^3 claim
+    assert fused_brick_ceiling(2, "int8") >= 72      # tile-padded, 16 MiB
     b = (32, 32, 32)
-    assert fused_working_set_bytes(b, 3, "int8", lut_width=13) < \
+    assert fused_working_set_bytes(b, 3, "int8") < \
         fused_working_set_bytes(b, 3, "f32")
+
+
+@pytest.mark.parametrize("kernel,bx", [("int8", 25), ("f32", 10),
+                                       ("energy", 10)])
+def test_over_budget_fallback_tile_fits_budget(kernel, bx):
+    """At the paper's one-chip brick (100^3) the fused kernel is over the
+    default budget; the fallback tile is the largest divisor of 100 whose
+    double-buffered slab fits, and twice that tile does not."""
+    brick = (100, 100, 100)
+    assert fused_working_set_bytes(brick, 2, "int8") > DEFAULT_VMEM_BUDGET
+    assert pick_x_tile(brick, kernel) == bx
+    assert tiled_working_set_bytes(brick, bx, kernel) <= DEFAULT_VMEM_BUDGET
+    assert tiled_working_set_bytes(brick, 2 * bx, kernel) > \
+        DEFAULT_VMEM_BUDGET
+
+
+def test_int8_engine_fallback_picks_fitting_tile():
+    prob = build_ea3d_lattice(8, seed=1)
+    mesh = make_mesh((1,), ("data",), axis_types=auto_axes(1))
+    budget = 1 << 19          # 8^3 int8 fused needs more; a 2-plane slab fits
+    with pytest.warns(RuntimeWarning, match="x-tiles of"):
+        eng = LatticeDSIM(prob, mesh, dim_axes=("data", None, None),
+                          precision="int8", impl="ref",
+                          vmem_budget_bytes=budget)
+    assert eng.kernel_path == "per_phase" and eng.fallback_reason == "vmem"
+    assert eng.fused_working_set > budget
+    assert 8 % eng.kernel_bx == 0
+    assert tiled_working_set_bytes(eng.brick, eng.kernel_bx, "int8") <= budget
+    with pytest.raises(ValueError, match="no x-tile"):
+        LatticeDSIM(prob, mesh, dim_axes=("data", None, None),
+                    precision="int8", impl="ref", vmem_budget_bytes=1024)
 
 
 # -- registry guards ----------------------------------------------------------

@@ -40,6 +40,8 @@ from .pbit import (FixedPoint, LUT_SELECT_MAX_WIDTH, bitplane_planes,
                    field_bound, flips_publish, lfsr_init,
                    quantize_couplings, threshold_lut_cached)
 from repro.compat import shard_map
+from repro.obs import programs
+from repro.obs.trace import span
 from repro.engines.base import (RecordedCursor, check_lanes,
                                 run_recorded_driver, spawn_seeds)
 from repro.kernels.ops import (pbit_update_op, pbit_sweep_op,
@@ -216,6 +218,7 @@ class LatticeDSIM:
                  degrade: Union[None, str, DegradePolicy] = None):
         if precision not in ("f32", "int8", "bitplane"):
             raise ValueError(f"unknown precision {precision!r}")
+        programs.watch()      # traces and compiles counted per program
         self.p = prob
         self.mesh = mesh
         self.dim_axes = dim_axes
@@ -468,23 +471,30 @@ class LatticeDSIM:
         cached = getattr(self, "_exchange_only_fn", None)
         if cached is not None:
             return cached
-        word = self.precision == "bitplane"
+        smapped = self._halo_program()
+
+        @jax.jit
+        def lattice_exchange_only(m):
+            return smapped(m)
+
+        fn = lambda state: lattice_exchange_only(state.m)  # noqa: E731
+        self._exchange_only_fn = fn
+        return fn
+
+    def _halo_program(self):
+        """The six-face halo exchange of the spins alone, as a fresh
+        ``shard_map``: ``m -> halos`` in the state's halo layout."""
+        exchange = self._exchange_block_w if self.precision == "bitplane" \
+            else self._exchange_block
 
         def block(m):
-            xlo, xhi, ylo, yhi, zlo, zhi = (
-                self._exchange_block_w(m) if word
-                else self._exchange_block(m))
+            xlo, xhi, ylo, yhi, zlo, zhi = exchange(m)
             return (xlo[:, None], xhi[:, None],
                     ylo[:, :, None, :], yhi[:, :, None, :],
                     zlo[:, :, :, None], zhi[:, :, :, None])
 
-        smapped = shard_map(block, mesh=self.mesh,
-                            in_specs=(self.spec_m,),
-                            out_specs=self.halo_specs, check_vma=False)
-        run = jax.jit(lambda m: smapped(m))
-        fn = lambda state: run(state.m)  # noqa: E731
-        self._exchange_only_fn = fn
-        return fn
+        return shard_map(block, mesh=self.mesh, in_specs=(self.spec_m,),
+                         out_specs=self.halo_specs, check_vma=False)
 
     # -- block step -------------------------------------------------------------------
 
@@ -720,7 +730,8 @@ class LatticeDSIM:
         )
 
         @jax.jit
-        def run(state: LatticeState, sched, masks, h, w6, *lut_opt):
+        def lattice_chunk(state: LatticeState, sched, masks, h, w6,
+                          *lut_opt):
             m, s, halos, fl = smapped(state.m, state.s, state.halos,
                                       sched, masks, h, w6, *lut_opt)
             return LatticeState(
@@ -728,8 +739,8 @@ class LatticeDSIM:
                 sweep=state.sweep + sched.shape[0] * sched.shape[1],
                 flips=flips_publish(state.flips, fl))
 
-        self._chunk_cache[key] = run
-        return run
+        self._chunk_cache[key] = lattice_chunk
+        return lattice_chunk
 
     def _run_chunk_bp(self, iters: int, S: int):
         """Bitplane chunk runner: words sweep via the multi-spin-coded op;
@@ -778,8 +789,8 @@ class LatticeDSIM:
         )
 
         @jax.jit
-        def run(state: BitplaneLatticeState, sched, masks_w, signs, nz,
-                base, lut):
+        def lattice_chunk(state: BitplaneLatticeState, sched, masks_w,
+                          signs, nz, base, lut):
             mw, s, halos, fl = smapped(state.m, state.s, state.halos,
                                        sched, masks_w, signs, nz, base, lut)
             return BitplaneLatticeState(
@@ -787,8 +798,8 @@ class LatticeDSIM:
                 sweep=state.sweep + sched.shape[0] * sched.shape[1],
                 flips=flips_publish(state.flips, fl))
 
-        self._chunk_cache[key] = run
-        return run
+        self._chunk_cache[key] = lattice_chunk
+        return lattice_chunk
 
     def _run_chunk_deg(self, iters: int, S: int, per_rep: bool,
                        freeze: bool, has_codes: bool):
@@ -843,7 +854,8 @@ class LatticeDSIM:
         )
 
         @jax.jit
-        def run(state: LatticeState, sched, masks, h, w6, health, *rest):
+        def lattice_chunk(state: LatticeState, sched, masks, h, w6, health,
+                          *rest):
             m, s, halos, fl, health = smapped(
                 state.m, state.s, state.halos, sched, masks, h, w6,
                 health, *rest)
@@ -853,8 +865,8 @@ class LatticeDSIM:
                 flips=flips_publish(state.flips, fl))
             return st, health
 
-        self._chunk_cache[key] = run
-        return run
+        self._chunk_cache[key] = lattice_chunk
+        return lattice_chunk
 
     def _run_chunk_bp_deg(self, iters: int, S: int, freeze: bool,
                           has_codes: bool):
@@ -908,8 +920,8 @@ class LatticeDSIM:
         )
 
         @jax.jit
-        def run(state: BitplaneLatticeState, sched, masks_w, signs, nz,
-                base, lut, health, *rest):
+        def lattice_chunk(state: BitplaneLatticeState, sched, masks_w,
+                          signs, nz, base, lut, health, *rest):
             mw, s, halos, fl, health = smapped(
                 state.m, state.s, state.halos, sched, masks_w, signs, nz,
                 base, lut, health, *rest)
@@ -919,8 +931,8 @@ class LatticeDSIM:
                 flips=flips_publish(state.flips, fl))
             return st, health
 
-        self._chunk_cache[key] = run
-        return run
+        self._chunk_cache[key] = lattice_chunk
+        return lattice_chunk
 
     def set_exchange_faults(self, codes):
         """Schedule engine-boundary exchange faults: ``codes[seq]`` in
@@ -962,32 +974,39 @@ class LatticeDSIM:
                 raise ValueError(f"need exactly R={R} seeds, got {len(seeds)}")
         else:
             seeds = [seed] if R == 1 else spawn_seeds(seed, R)
-        ms, ss = [], []
-        for sd in seeds:
-            rng = np.random.default_rng(sd)
-            ms.append(rng.choice(np.array([-1, 1], np.int8), size=(X, Y, Z)))
-            ss.append(np.asarray(lfsr_init(X * Y * Z, sd)).reshape(X, Y, Z))
-        s = jnp.asarray(np.stack(ss))
-        if self.precision == "bitplane":
-            # lane r's spins and LFSR column come from seeds[r] exactly as
-            # replica r of the unpacked engines would — lane r of a packed
-            # run is bit-identical to int8 replica r at matched schedules
-            mw = pack_lanes(jnp.asarray(np.stack(ms)))
-            halos = tuple(jnp.zeros(sh, jnp.uint32)
-                          for sh in self._halo_shapes())
-            st = BitplaneLatticeState(m=mw, s=s, halos=halos,
+        with span("lattice.init_state"):
+            ms, ss = [], []
+            with span("lattice.init_state.draw"):
+                for sd in seeds:
+                    rng = np.random.default_rng(sd)
+                    ms.append(rng.choice(np.array([-1, 1], np.int8),
+                                         size=(X, Y, Z)))
+                    ss.append(np.asarray(lfsr_init(X * Y * Z, sd))
+                              .reshape(X, Y, Z))
+            with span("lattice.init_state.put"):
+                s = jnp.asarray(np.stack(ss))
+                if self.precision == "bitplane":
+                    # lane r's spins and LFSR column come from seeds[r]
+                    # exactly as replica r of the unpacked engines would —
+                    # lane r of a packed run is bit-identical to int8
+                    # replica r at matched schedules
+                    mw = pack_lanes(jnp.asarray(np.stack(ms)))
+                    halos = tuple(jnp.zeros(sh, jnp.uint32)
+                                  for sh in self._halo_shapes())
+                    st = BitplaneLatticeState(
+                        m=mw, s=s, halos=halos,
+                        sweep=jnp.zeros((), jnp.int32),
+                        flips=jnp.zeros((R,), jnp.int32))
+                else:
+                    m = jnp.asarray(np.stack(ms))
+                    halos = tuple(jnp.zeros(sh, jnp.int8)
+                                  for sh in self._halo_shapes())
+                    st = LatticeState(m=m, s=s, halos=halos,
                                       sweep=jnp.zeros((), jnp.int32),
                                       flips=jnp.zeros((R,), jnp.int32))
-        else:
-            m = jnp.asarray(np.stack(ms))
-            halos = tuple(jnp.zeros(sh, jnp.int8)
-                          for sh in self._halo_shapes())
-            st = LatticeState(m=m, s=s, halos=halos,
-                              sweep=jnp.zeros((), jnp.int32),
-                              flips=jnp.zeros((R,), jnp.int32))
-        st = self.shard_state(st)
-        # one synchronizing exchange so the first sweeps see real halos
-        return self._refresh_halos(st)
+                st = self.shard_state(st)
+            # one synchronizing exchange so the first sweeps see real halos
+            return self._refresh_halos(st)
 
     def shard_state(self, st):
         # drop the cached exchange-only closure: it closed over the old
@@ -1005,27 +1024,20 @@ class LatticeDSIM:
             sweep=put(st.sweep, self._shard(P())),
             flips=put(st.flips, self._shard(P())))
 
-    def _refresh_halos(self, st):
-        if self.precision == "bitplane":
-            def block(mw):
-                xlo, xhi, ylo, yhi, zlo, zhi = self._exchange_block_w(mw)
-                return (xlo[:, None], xhi[:, None],
-                        ylo[:, :, None, :], yhi[:, :, None, :],
-                        zlo[:, :, :, None], zhi[:, :, :, None])
-            halos = jax.jit(shard_map(
-                block, mesh=self.mesh, in_specs=(self.spec_m,),
-                out_specs=self.halo_specs, check_vma=False))(st.m)
-            return dataclasses.replace(st, halos=halos)
+    def _halo_refresh_fn(self):
+        """A new jitted halo refresh (traced again on every call of it)."""
+        smapped = self._halo_program()
 
-        def block(m):
-            xlo, xhi, ylo, yhi, zlo, zhi = self._exchange_block(m)
-            return (xlo[:, None], xhi[:, None],
-                    ylo[:, :, None, :], yhi[:, :, None, :],
-                    zlo[:, :, :, None], zhi[:, :, :, None])
-        halos = jax.jit(shard_map(
-            block, mesh=self.mesh, in_specs=(self.spec_m,),
-            out_specs=self.halo_specs, check_vma=False))(st.m)
-        return dataclasses.replace(st, halos=halos)
+        @jax.jit
+        def lattice_halo_refresh(m):
+            return smapped(m)
+
+        return lattice_halo_refresh
+
+    def _refresh_halos(self, st):
+        with span("lattice.halo_refresh"):
+            halos = self._halo_refresh_fn()(st.m)
+            return dataclasses.replace(st, halos=halos)
 
     def run_recorded_full(self, state: LatticeState, schedule,
                           record_points: Sequence[int], sync_every: int = 1,
@@ -1133,7 +1145,7 @@ class LatticeDSIM:
             R = self.replicas
             bitplane = self.precision == "bitplane"
 
-            def block(m, active, h, w6):
+            def lattice_energy(m, active, h, w6):
                 if bitplane:
                     # unpack lanes + word halos, then the shared per-replica
                     # energy readout — identical float ops to the unpacked
@@ -1151,7 +1163,7 @@ class LatticeDSIM:
                 return jax.lax.psum(e, axes_all) if axes_all else e
 
             self._energy_fn = jax.jit(shard_map(
-                block, mesh=self.mesh,
+                lattice_energy, mesh=self.mesh,
                 in_specs=(self.spec_m, self.spec_flat, self.spec_flat,
                           tuple(self.spec_flat for _ in range(6))),
                 out_specs=P(), check_vma=False))

@@ -60,6 +60,7 @@ ENGINE_PRECISIONS = {
 }
 # canonical word-format constants live next to the packing routines
 from repro.core.packing import LANE_WIDTH, MAX_LANE_WORDS  # noqa: E402
+from repro.obs.trace import span  # noqa: E402
 
 
 def lanes_of(precision: str) -> int:
@@ -311,12 +312,13 @@ class RecordedCursor:
         return self._flips_total
 
     def _read_flips(self):
-        cur = _flips_read(self._flips_of(self.state))
-        delta = (cur - self._prev) % (1 << 32)
-        self.flips_vec += delta
-        self._flips_total += int(delta.sum())
-        self._prev = cur
-        self._pending = 0
+        with span("cursor.read_flips"):
+            cur = _flips_read(self._flips_of(self.state))
+            delta = (cur - self._prev) % (1 << 32)
+            self.flips_vec += delta
+            self._flips_total += int(delta.sum())
+            self._prev = cur
+            self._pending = 0
 
     def advance(self, max_chunks: int = 1) -> int:
         """Run up to ``max_chunks`` plan chunks; returns how many ran."""
@@ -330,19 +332,20 @@ class RecordedCursor:
             if self._flips_of is not None and self._flips_per_sweep and \
                     self._pending + worst >= self._LIMIT:
                 self._read_flips()
-            # trailing dims (e.g. a per-replica axis) ride along untouched
-            bchunk = jnp.asarray(
-                self._betas[self._pos:self._pos + nsw]).reshape(
-                    (c, self.S) + self._betas.shape[1:])
-            if self.chunk_timer is not None:
-                import jax
-                jax.block_until_ready(self.state)
-                t0 = time.perf_counter()
-                self.state = self._chunk_fn(self.state, bchunk, c, self.S)
-                jax.block_until_ready(self.state)
-                self.chunk_timer(nsw, time.perf_counter() - t0)
-            else:
-                self.state = self._chunk_fn(self.state, bchunk, c, self.S)
+            with span("cursor.chunk"):
+                # trailing dims (e.g. a per-replica axis) ride along untouched
+                bchunk = jnp.asarray(
+                    self._betas[self._pos:self._pos + nsw]).reshape(
+                        (c, self.S) + self._betas.shape[1:])
+                if self.chunk_timer is not None:
+                    import jax
+                    jax.block_until_ready(self.state)
+                    t0 = time.perf_counter()
+                    self.state = self._chunk_fn(self.state, bchunk, c, self.S)
+                    jax.block_until_ready(self.state)
+                    self.chunk_timer(nsw, time.perf_counter() - t0)
+                else:
+                    self.state = self._chunk_fn(self.state, bchunk, c, self.S)
             self._i += 1
             self._pos += nsw
             self._pending += worst
@@ -350,7 +353,8 @@ class RecordedCursor:
             if self._flips_of is not None and self._flips_per_sweep is None:
                 self._read_flips()   # unknown bound: stay exact per chunk
             if self._pos in self._targets:
-                self._out.append(self._record_fn(self.state))
+                with span("cursor.readout"):
+                    self._out.append(self._record_fn(self.state))
                 self._times.append(self._pos)
                 if self._flips_of is not None:
                     self._read_flips()
@@ -370,11 +374,12 @@ class RecordedCursor:
         :meth:`run_to_completion` it is free.  With no record points hit
         yet, ``energies`` is an empty (0,) array.
         """
-        if self._flips_of is not None and self._pending:
-            self._read_flips()
-        obs = jnp.stack(self._out) if self._out else jnp.zeros((0,))
-        return RunRecord(np.asarray(self._times, np.int64), obs,
-                         self._flips_total)
+        with span("cursor.record"):
+            if self._flips_of is not None and self._pending:
+                self._read_flips()
+            obs = jnp.stack(self._out) if self._out else jnp.zeros((0,))
+            return RunRecord(np.asarray(self._times, np.int64), obs,
+                             self._flips_total)
 
     def warm(self):
         """Execute each distinct chunk length once, discarding the result.

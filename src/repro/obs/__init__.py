@@ -1,13 +1,17 @@
 """Runtime telemetry fabric: metrics, tracing, and measured-η timing.
 
-Dependency-free (stdlib + the repo's own commcost model; jax is only
-imported lazily at explicit sync boundaries).  Three layers:
+Stdlib, the repo's own commcost model and jax's profiler annotations
+(the rest of jax is imported lazily, at explicit sync boundaries).
+Four layers:
 
 * :mod:`.metrics` — thread-safe :class:`MetricsRegistry` of counters,
   gauges, and fixed-bucket histograms with labeled children, JSON
   snapshots, and Prometheus text exposition;
 * :mod:`.trace` — bounded-ring span :class:`Tracer` with an explicit
-  ``block_until_ready`` boundary for device-async attribution;
+  ``block_until_ready`` boundary for device-async attribution, every span
+  on the profiler's clock; :func:`span` for the program's hot path;
+* :mod:`.programs` — per-program counts of JAX traces and compiles
+  (``jax_traces_total{program}``, ``jax_compiles_total{program}``);
 * :mod:`.timing` — :class:`EtaMeter`, which turns per-chunk wall time
   plus exchange-only collective time into measured η = f_comm/f_pbit
   and its margin against ``commcost.eta_threshold``.
@@ -16,11 +20,11 @@ imported lazily at explicit sync boundaries).  Three layers:
 from .metrics import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry)
 from .timing import EtaMeter, dist_eta_meter, exchanges_per_sweep
-from .trace import Span, Tracer
+from .trace import Span, Tracer, install, span
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "DEFAULT_TIME_BUCKETS",
-    "Tracer", "Span",
+    "Tracer", "Span", "span", "install",
     "EtaMeter", "dist_eta_meter", "exchanges_per_sweep",
 ]

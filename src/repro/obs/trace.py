@@ -7,6 +7,15 @@ a bounded in-memory ring (oldest evicted first).  Spans nest per thread
 wave's ``serve_wave.drain`` span owns its per-job children without any
 global context plumbing.
 
+Every span is also a ``jax.profiler.TraceAnnotation``: under a profiler
+it lands on the host timeline, the clock the device's operations are on,
+so an idle gap of the device can be put down to the span open at the
+time.  The program's hot path (engine chunks, readouts, ``init_state``)
+uses the module-level :func:`span`: a bare annotation (a no-op without a
+profiler) unless :func:`install` gave it a :class:`Tracer` whose ring
+should record those spans too.  Hot-path spans carry no attributes and
+no sync, so they add no host<->device synchronisation and no device op.
+
 JAX dispatch is asynchronous: a chunk launch returns before the device
 finishes, so a naive ``perf_counter`` pair around ``chunk_fn`` would
 attribute device time to whichever *later* span happens to block.  A
@@ -14,21 +23,22 @@ span therefore carries an explicit sync boundary: ``sp.sync(value)``
 stashes a pytree (e.g. the returned state) and the tracer calls
 ``jax.block_until_ready`` on it *before* taking the end timestamp, so
 device work lands in the span that launched it.  The blocker is lazy
-and injectable — nothing here imports jax unless a span actually syncs,
-keeping the module dependency-free for pure-host users and tests.
+and injectable — only a span that syncs needs more of jax than its
+profiler annotations.
 """
 
 from __future__ import annotations
 
 import contextlib
 import itertools
-import json
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, List, Optional
 
-__all__ = ["Span", "Tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Tracer", "span", "install"]
 
 
 def _default_block(value: Any) -> None:
@@ -108,16 +118,17 @@ class Tracer:
         if sync is not None:
             sp._sync = sync
         stack.append(sp)
-        try:
-            yield sp
-        finally:
-            if sp._sync is not None:
-                self._block(sp._sync)
-            sp.t1 = self._clock()
-            if stack and stack[-1] is sp:
-                stack.pop()
-            with self._lock:
-                self._ring.append(sp)
+        with TraceAnnotation(name):
+            try:
+                yield sp
+            finally:
+                if sp._sync is not None:
+                    self._block(sp._sync)
+                sp.t1 = self._clock()
+                if stack and stack[-1] is sp:
+                    stack.pop()
+                with self._lock:
+                    self._ring.append(sp)
 
     # -- readers --------------------------------------------------------------------
 
@@ -136,10 +147,23 @@ class Tracer:
         with self._lock:
             self._ring.clear()
 
-    def export_jsonl(self, path: str) -> int:
-        """Append every finished span as one JSON line; returns count."""
-        rows = self.spans()
-        with open(path, "a") as f:
-            for r in rows:
-                f.write(json.dumps(r, default=str) + "\n")
-        return len(rows)
+
+_installed: Optional[Tracer] = None
+
+
+def install(tracer: Optional[Tracer]) -> Optional[Tracer]:
+    """Record the program's hot-path spans (:func:`span`) in ``tracer``'s
+    ring as well as on the profiler's timeline; ``None`` stops recording.
+    Returns the tracer it replaces."""
+    global _installed
+    prev, _installed = _installed, tracer
+    return prev
+
+
+def span(name: str):
+    """A hot-path span: a profiler annotation, and a ring span of the
+    installed :class:`Tracer` when there is one."""
+    tracer = _installed
+    if tracer is None:
+        return TraceAnnotation(name)
+    return tracer.span(name)

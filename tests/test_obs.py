@@ -17,7 +17,7 @@ from repro.core import commcost
 from repro.core.coloring import lattice3d_coloring
 from repro.core.graph import ea3d
 from repro.obs import (DEFAULT_TIME_BUCKETS, EtaMeter, MetricsRegistry,
-                       Tracer, exchanges_per_sweep)
+                       Tracer, exchanges_per_sweep, install, span)
 from repro.serve import SampleServer
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -136,7 +136,7 @@ def test_histogram_percentiles_interpolate():
 
 # -- tracer -------------------------------------------------------------------
 
-def test_tracer_spans_nest_and_export(tmp_path):
+def test_tracer_spans_nest_and_export():
     clk = iter(np.arange(0.0, 100.0, 0.5))
     synced = []
     tr = Tracer(clock=lambda: float(next(clk)), capacity=8,
@@ -154,15 +154,29 @@ def test_tracer_spans_nest_and_export(tmp_path):
     assert by["inner"]["duration_s"] == pytest.approx(0.5)  # one tick
     assert synced == [{"state": 1}]             # block ran before t1
     assert tr.durations("outer") == [pytest.approx(1.5)]
-    p = tmp_path / "spans.jsonl"
-    assert tr.export_jsonl(str(p)) == 2
-    rows = [json.loads(line) for line in p.read_text().splitlines()]
-    assert {r["name"] for r in rows} == {"inner", "outer"}
     # bounded ring: old spans evicted
     for i in range(20):
         with tr.span(f"s{i}"):
             pass
     assert len(tr.spans()) == 8
+
+
+def test_hot_path_span_is_a_bare_annotation_until_installed():
+    from jax.profiler import TraceAnnotation
+    prev = install(None)
+    try:
+        assert type(span("cursor.chunk")) is TraceAnnotation
+        tr = Tracer()
+        install(tr)
+        with span("cursor.chunk"):
+            with span("cursor.readout"):
+                pass
+    finally:
+        install(prev)
+    spans = tr.spans()
+    assert [s["name"] for s in spans] == ["cursor.readout", "cursor.chunk"]
+    assert spans[0]["parent_id"] == spans[1]["span_id"]
+    assert all(s["attrs"] == {} for s in spans)
 
 
 # -- EtaMeter vs commcost -----------------------------------------------------

@@ -9,19 +9,28 @@ its observable.
 
 Flip accounting: device-side counters are int32 (TPU-native), which wraps
 after ~2.1e9 flips — minutes of runtime at the paper's 1e12 flips/s.  The
-driver therefore treats the device counter as a modular odometer: it reads
-it once per chunk, takes the delta mod 2**32, and accumulates the exact
-total in a host-side Python int (arbitrary precision, so >= int64 by
-construction).  ``chunk_plan(max_chunk=...)`` bounds the per-chunk delta
-below 2**31 so the modular delta is unambiguous.
+driver therefore treats the device counter as a modular odometer.  At each
+record point, and before the worst-case flips since the last snapshot
+could reach 2**31, it takes a device-side snapshot of the counter (its
+host copy started, nothing waited on).  Only when a caller asks for flips
+does it settle: one host read of every pending snapshot, folded in order
+as deltas mod 2**32 into the exact total, a host-side Python int
+(arbitrary precision, so >= int64 by construction).  Each delta spans two
+consecutive snapshots, which the bound keeps below 2**31 worst-case flips
+apart, so it is unambiguous however many snapshots one settle folds;
+``chunk_plan(max_chunk=...)`` keeps a single chunk below that bound too.
+The host runs at most two chunks ahead of the device: before it
+dispatches a third, it waits on the oldest one's flip counter.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Any, Callable, List, Optional, Protocol, Sequence, Union, runtime_checkable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -60,6 +69,7 @@ ENGINE_PRECISIONS = {
 }
 # canonical word-format constants live next to the packing routines
 from repro.core.packing import LANE_WIDTH, MAX_LANE_WORDS  # noqa: E402
+from repro.obs import flip_syncs  # noqa: E402
 from repro.obs.trace import span  # noqa: E402
 
 
@@ -217,6 +227,12 @@ def _flips_read(value) -> np.ndarray:
     return np.atleast_1d(np.asarray(value)).astype(np.int64) % (1 << 32)
 
 
+# Chunks the host may have dispatched and not seen finish.  At two the
+# device always has the next chunk queued while the host prepares the one
+# after, and the host never queues a whole anneal of state buffers.
+_IN_FLIGHT = 2
+
+
 class RecordedCursor:
     """The shared recording loop in resumable form.
 
@@ -233,6 +249,12 @@ class RecordedCursor:
     flips so far); :attr:`flips_vec` additionally keeps the per-counter
     (e.g. per-replica) totals so a multi-tenant caller can attribute flips
     to the replica slices it packed into one batched run.
+
+    Advancing never reads the flip counters on the host: record and bound
+    points take device-side snapshots, which :meth:`record`,
+    :meth:`run_to_completion`, :meth:`checkpoint` and reads of
+    :attr:`flips` / :attr:`flips_vec` settle in one host read.  At most
+    :data:`_IN_FLIGHT` dispatched chunks are left unconfirmed.
     """
 
     def __init__(self, *, state, schedule, record_points: Sequence[int],
@@ -276,17 +298,18 @@ class RecordedCursor:
         # obs.EtaMeter attaches here).  When set, each chunk is bracketed
         # by block_until_ready so device-async work is attributed to the
         # chunk that launched it; when None (default) no sync is added
-        # and the lazy-flip-read fast path is untouched.
+        # and chunks stay in flight.
         self.chunk_timer: Optional[Callable] = None
-        # The device counter is read lazily: at record points (which
-        # synchronize anyway for the observable) and just before the
-        # worst-case flips since the last read could reach 2**31 (keeping
-        # the modular delta unambiguous).  Chunks never end with a
-        # gratuitous host sync.
+        # Flip counters (module docstring): `_prev` is the last settled
+        # value, `_snaps` the snapshots taken since.
         self._prev = _flips_read(flips_of(state)) if flips_of is not None \
             else None
-        self._pending = 0            # worst-case flips since `_prev` was read
-        self.flips_vec = None if self._prev is None else \
+        self._pending = 0            # worst-case flips since the last snapshot
+        self._snaps: List[Any] = []  # snapshots not yet folded into totals
+        self._snap_pos = 0           # sweep position of the newest snapshot
+        # (sweep position, flip counter) of chunks not yet seen finished
+        self._inflight: collections.deque = collections.deque()
+        self._flips_vec = None if self._prev is None else \
             np.zeros(self._prev.shape, np.int64)
         self._flips_total = 0        # exact host total (Python int)
 
@@ -308,17 +331,65 @@ class RecordedCursor:
 
     @property
     def flips(self) -> int:
-        """Exact flips up to the last counter read (no device sync)."""
+        """Exact flips up to the last record or bound point (settles the
+        snapshots taken since the last read)."""
+        self._read_flips()
         return self._flips_total
 
+    @property
+    def flips_vec(self) -> Optional[np.ndarray]:
+        """Exact per-counter totals up to the last record or bound point
+        (settles like :attr:`flips`); None without counters."""
+        self._read_flips()
+        return self._flips_vec
+
+    @flips_vec.setter
+    def flips_vec(self, vec):
+        self._flips_vec = vec
+
+    def _snapshot(self):
+        """Take the current flip counters without a host read."""
+        cnt = self._flips_of(self.state)
+        if isinstance(cnt, jax.Array):
+            cnt.copy_to_host_async()
+        else:
+            cnt = np.array(cnt)
+        self._snaps.append(cnt)
+        self._snap_pos = self._pos
+        self._pending = 0
+        flip_syncs.count("snapshot")
+
     def _read_flips(self):
+        """Settle: fold every pending snapshot, in order, into the exact
+        totals, with one host read of them all."""
+        if not self._snaps:
+            return
         with span("cursor.read_flips"):
-            cur = _flips_read(self._flips_of(self.state))
-            delta = (cur - self._prev) % (1 << 32)
-            self.flips_vec += delta
-            self._flips_total += int(delta.sum())
-            self._prev = cur
-            self._pending = 0
+            for value in jax.device_get(self._snaps):
+                cur = _flips_read(value)
+                delta = (cur - self._prev) % (1 << 32)
+                self._flips_vec += delta
+                self._flips_total += int(delta.sum())
+                self._prev = cur
+            self._snaps = []
+            while self._inflight and self._inflight[0][0] <= self._snap_pos:
+                self._inflight.popleft()     # finished: its result was read
+            flip_syncs.count("settle")
+
+    def _sync_flips(self):
+        """Exact flips up to the current position."""
+        if self._flips_of is not None and self._pending:
+            self._snapshot()
+        self._read_flips()
+
+    def _wait_in_flight(self):
+        """Leave the device at most one chunk queued behind the running
+        one before the next is dispatched."""
+        if len(self._inflight) < _IN_FLIGHT:
+            return
+        with span("cursor.wait"):
+            jax.block_until_ready(self._inflight.popleft()[1])
+        flip_syncs.count("wait")
 
     def advance(self, max_chunks: int = 1) -> int:
         """Run up to ``max_chunks`` plan chunks; returns how many ran."""
@@ -331,14 +402,14 @@ class RecordedCursor:
             worst = nsw * (self._flips_per_sweep or 0)
             if self._flips_of is not None and self._flips_per_sweep and \
                     self._pending + worst >= self._LIMIT:
-                self._read_flips()
+                self._snapshot()
+            self._wait_in_flight()
             with span("cursor.chunk"):
                 # trailing dims (e.g. a per-replica axis) ride along untouched
                 bchunk = jnp.asarray(
                     self._betas[self._pos:self._pos + nsw]).reshape(
                         (c, self.S) + self._betas.shape[1:])
                 if self.chunk_timer is not None:
-                    import jax
                     jax.block_until_ready(self.state)
                     t0 = time.perf_counter()
                     self.state = self._chunk_fn(self.state, bchunk, c, self.S)
@@ -350,20 +421,23 @@ class RecordedCursor:
             self._pos += nsw
             self._pending += worst
             ran += 1
+            if self._flips_of is not None and self.chunk_timer is None:
+                cnt = self._flips_of(self.state)
+                if isinstance(cnt, jax.Array):
+                    self._inflight.append((self._pos, cnt))
             if self._flips_of is not None and self._flips_per_sweep is None:
-                self._read_flips()   # unknown bound: stay exact per chunk
+                self._snapshot()     # unknown bound: a snapshot per chunk
             if self._pos in self._targets:
                 with span("cursor.readout"):
                     self._out.append(self._record_fn(self.state))
                 self._times.append(self._pos)
                 if self._flips_of is not None:
-                    self._read_flips()
+                    self._snapshot()
         return ran
 
     def run_to_completion(self):
         self.advance(max_chunks=len(self._plan))
-        if self._flips_of is not None and self._pending:
-            self._read_flips()
+        self._sync_flips()
         return self
 
     def record(self) -> RunRecord:
@@ -375,8 +449,7 @@ class RecordedCursor:
         yet, ``energies`` is an empty (0,) array.
         """
         with span("cursor.record"):
-            if self._flips_of is not None and self._pending:
-                self._read_flips()
+            self._sync_flips()
             obs = jnp.stack(self._out) if self._out else jnp.zeros((0,))
             return RunRecord(np.asarray(self._times, np.int64), obs,
                              self._flips_total)
@@ -391,7 +464,6 @@ class RecordedCursor:
         The record observable is warmed too (it may be jitted, e.g. the
         partitioned engines' energy readout).
         """
-        import jax
         seen = set()
         for c in self._plan[self._i:]:
             if c in seen:
@@ -423,8 +495,7 @@ class RecordedCursor:
         pending flip window first, so the checkpoint's counters are exact
         at this boundary.
         """
-        if self._flips_of is not None and self._pending:
-            self._read_flips()
+        self._sync_flips()
         snap = self.state if snapshot_fn is None else snapshot_fn(self.state)
         return {
             "format": self._CK_FORMAT,
@@ -437,8 +508,8 @@ class RecordedCursor:
             "out": [np.asarray(o) for o in self._out],
             "prev": None if self._prev is None else self._prev.copy(),
             "pending": self._pending,
-            "flips_vec": None if self.flips_vec is None
-            else self.flips_vec.copy(),
+            "flips_vec": None if self._flips_vec is None
+            else self._flips_vec.copy(),
             "flips_total": self._flips_total,
             "state": snap,
         }
@@ -475,6 +546,8 @@ class RecordedCursor:
         self._prev = None if ck["prev"] is None \
             else np.asarray(ck["prev"]).copy()
         self._pending = int(ck["pending"])
+        self._snaps = []
+        self._inflight.clear()
         self.flips_vec = None if ck["flips_vec"] is None \
             else np.asarray(ck["flips_vec"]).copy()
         self._flips_total = int(ck["flips_total"])
@@ -533,5 +606,4 @@ def spawn_seeds(seed: int, replicas: int) -> List[int]:
 
 def stack_states(states: Sequence[Any]):
     """Stack per-replica state pytrees along a new leading replica axis."""
-    import jax
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *states)

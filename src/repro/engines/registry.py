@@ -129,7 +129,8 @@ class HandleCursor:
         return RunRecord(rec.times, e, rec.flips)
 
     def flips_per_replica(self) -> np.ndarray:
-        """(R,) exact per-replica flip totals up to the last counter read."""
+        """(R,) exact per-replica flip totals up to the last record or
+        bound point (settles the cursor's pending snapshots)."""
         vec = self._c.flips_vec
         if vec is None:
             return np.zeros((self.replicas,), np.int64)

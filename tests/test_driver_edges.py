@@ -1,6 +1,9 @@
 """Shared-driver edge cases: chunk planning, record-point quantization,
 flip-cap bounds, and the resumable RecordedCursor surface."""
 
+import pickle
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -9,8 +12,10 @@ from repro.core.annealing import constant_schedule, ea_schedule
 from repro.core.coloring import lattice3d_coloring
 from repro.core.graph import ea3d
 from repro.engines import make_engine
+from repro.core.lattice import build_ea3d_lattice
 from repro.engines.base import (RecordedCursor, chunk_plan, flips_chunk_cap,
                                 quantize_record_points, run_recorded_driver)
+from repro.obs import Tracer, flip_syncs, install
 
 
 # -- chunk_plan ----------------------------------------------------------------
@@ -217,6 +222,122 @@ def test_cursor_empty_partial_record(gibbs_handle):
     cur = h.start_recorded(h.init_state(seed=0), ea_schedule(SW), [SW])
     rec = cur.record()                       # before any advance
     assert len(rec.times) == 0 and rec.flips == 0
+
+
+# -- device-side flip snapshots ------------------------------------------------
+
+def _syncs() -> dict:
+    fam = flip_syncs.flip_syncs().counter(flip_syncs.FLIP_SYNCS)
+    return {k: fam.labels(kind=k).value for k in flip_syncs.KINDS}
+
+
+def _moved(before: dict) -> dict:
+    now = _syncs()
+    return {k: now[k] - before[k] for k in flip_syncs.KINDS}
+
+
+@pytest.fixture(scope="module")
+def lattice_int8_handle():
+    return make_engine("lattice", lattice=build_ea3d_lattice(8, seed=1),
+                       precision="int8", replicas=2)
+
+
+@pytest.mark.parametrize("record_each", [False, True],
+                         ids=["advance_only", "record_each"])
+@pytest.mark.parametrize("engine", ["gibbs", "lattice_int8"])
+def test_cursor_record_every_period_matches_one_shot(engine, record_each,
+                                                     request):
+    """A record point at every exchange period: snapshots taken at each
+    and settled late (or at each, when the caller records every time)
+    give the one-shot driver's times, energies and flips bit for bit."""
+    if engine == "gibbs":
+        h, S = request.getfixturevalue("gibbs_handle")[1], 1
+    else:
+        h, S = request.getfixturevalue("lattice_int8_handle"), 4
+    sch = ea_schedule(SW)
+    pts = list(range(S, SW + 1, S))
+    st1, rec1 = h.run_recorded(h.init_state(seed=3), sch, pts, sync_every=S)
+    ref = h.start_recorded(h.init_state(seed=3), sch, pts, sync_every=S)
+    ref.advance(len(pts))
+    cur = h.start_recorded(h.init_state(seed=3), sch, pts, sync_every=S)
+    while not cur.done:
+        cur.advance(1)
+        if record_each:
+            cur.record()
+    rec2 = cur.record()
+    assert np.array_equal(rec1.times, rec2.times)
+    assert np.array_equal(np.asarray(rec1.energies),
+                          np.asarray(rec2.energies))
+    assert rec1.flips == rec2.flips == cur.flips > 0
+    per_rep = cur.flips_per_replica()
+    assert np.array_equal(per_rep, ref.flips_per_replica())
+    assert int(per_rep.sum()) == rec1.flips
+    assert np.array_equal(np.asarray(h.global_spins(st1)),
+                          np.asarray(h.global_spins(cur.state)))
+
+
+@jax.jit
+def _fake_step(st, betas2d):
+    return {"flips": st["flips"] + jnp.int32(3 * betas2d.size),
+            "E": st["E"] + jnp.sum(betas2d)}
+
+
+def test_cursor_snapshots_without_host_reads():
+    """64 record points advanced without a record(): 64 device-side
+    snapshots, no settle, never more than two chunks unconfirmed; the
+    record() after them settles all 64 in one read."""
+    P = 64
+    blocked = []
+    tr = Tracer(block=blocked.append)
+    prev = install(tr)
+    try:
+        before = _syncs()
+        cur = RecordedCursor(
+            state={"flips": jnp.zeros((2,), jnp.int32), "E": jnp.zeros(())},
+            schedule=constant_schedule(1.0, P),
+            record_points=list(range(1, P + 1)),
+            chunk_fn=lambda st, b, iters, S: _fake_step(st, b),
+            record_fn=lambda st: st["E"], flips_of=lambda st: st["flips"],
+            flips_per_sweep=6)
+        unconfirmed = []
+        while not cur.done:
+            cur.advance(1)
+            unconfirmed.append(len(cur._inflight))
+        assert _moved(before) == {"snapshot": P, "settle": 0, "wait": P - 2}
+        assert max(unconfirmed) == 2
+        rec = cur.record()
+        assert _moved(before) == {"snapshot": P, "settle": 1, "wait": P - 2}
+    finally:
+        install(prev)
+    assert rec.flips == 6 * P and list(cur.flips_vec) == [3 * P, 3 * P]
+    assert list(rec.times) == list(range(1, P + 1))
+    assert "cursor.wait" in {s["name"] for s in tr.spans()}
+    assert blocked == []
+
+
+def test_checkpoint_with_snapshots_pending_resumes_bitwise(gibbs_handle):
+    g, h = gibbs_handle
+    sch = ea_schedule(SW)
+    pts = list(range(2, SW + 1, 2))
+    ref = h.start_recorded(h.init_state(seed=6), sch, pts)
+    ref.advance(len(pts))
+    cur = h.start_recorded(h.init_state(seed=6), sch, pts)
+    before = _syncs()
+    cur.advance(5)
+    assert _moved(before)["settle"] == 0     # five snapshots pending
+    ck = pickle.loads(pickle.dumps(cur.checkpoint()))
+    assert _moved(before)["settle"] == 1
+    fresh = h.start_recorded(h.init_state(seed=0), sch, pts)
+    fresh.restore_checkpoint(ck)
+    fresh.advance(len(pts))
+    rec, want = fresh.record(), ref.record()
+    assert np.array_equal(rec.times, want.times)
+    assert np.array_equal(np.asarray(rec.energies),
+                          np.asarray(want.energies))
+    assert rec.flips == want.flips
+    assert np.array_equal(fresh.flips_per_replica(), ref.flips_per_replica())
+    assert np.array_equal(np.asarray(h.global_spins(fresh.state)),
+                          np.asarray(h.global_spins(ref.state)))
 
 
 # -- snapshot / restore --------------------------------------------------------

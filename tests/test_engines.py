@@ -225,9 +225,14 @@ def test_chunk_plan_max_chunk():
         chunk_plan([4], max_chunk=3)
 
 
-def test_flip_total_exact_beyond_int32():
+@pytest.mark.parametrize("every_chunk", [False, True],
+                         ids=["one_point", "every_chunk"])
+def test_flip_total_exact_beyond_int32(every_chunk):
     """>2**31 flips accumulate exactly: the device counter is a wrapping
-    int32 odometer, the driver's host-side total is an exact Python int."""
+    int32 odometer, the driver's host-side total is an exact Python int.
+    With a record point at every chunk, 512 snapshots spanning two wraps
+    are settled in one read."""
+    from repro.obs import flip_syncs
     FLIPS_PER_SWEEP = 1 << 24
     TOTAL = 512                                   # 512 * 2^24 = 2^33 flips
     cap = flips_chunk_cap(FLIPS_PER_SWEEP, 1)
@@ -243,16 +248,20 @@ def test_flip_total_exact_beyond_int32():
         wrapped = np.uint32((int(state["flips"]) + d) & 0xFFFFFFFF)
         return FakeState(flips=wrapped, E=state["E"])
 
+    syncs = flip_syncs.flip_syncs().counter(flip_syncs.FLIP_SYNCS)
+    settles = syncs.labels(kind="settle").value
     state = FakeState(flips=np.uint32(0), E=jnp.zeros(()))
     state, rec = run_recorded_driver(
         state=state, schedule=constant_schedule(1.0, TOTAL),
-        record_points=[TOTAL], chunk_fn=chunk_fn,
+        record_points=list(range(1, TOTAL + 1)) if every_chunk else [TOTAL],
+        chunk_fn=chunk_fn,
         record_fn=lambda st: st["E"], sync_every=1,
         flips_of=lambda st: st["flips"],
         flips_per_sweep=FLIPS_PER_SWEEP)
     exact = TOTAL * FLIPS_PER_SWEEP
     assert exact > (1 << 31)
     assert rec.flips == exact                      # wrapped twice, still exact
+    assert syncs.labels(kind="settle").value == settles + 1
 
 
 def test_engine_flip_totals_consistent(setup):

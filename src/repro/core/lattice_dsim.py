@@ -40,7 +40,7 @@ from .pbit import (FixedPoint, LUT_SELECT_MAX_WIDTH, bitplane_planes,
                    field_bound, flips_publish, lfsr_init,
                    quantize_couplings, threshold_lut_cached)
 from repro.compat import shard_map
-from repro.obs import programs
+from repro.obs import exchanges, programs
 from repro.obs.trace import span
 from repro.engines.base import (RecordedCursor, check_lanes,
                                 run_recorded_driver, spawn_seeds)
@@ -284,6 +284,8 @@ class LatticeDSIM:
             if ext % k != 0:
                 raise ValueError(f"dim {d} extent {ext} not divisible by mesh factor {k}")
         self.brick = tuple(e // k for e, k in zip(prob.dims, self.nb))
+        # the ``link`` label of ``lattice_exchanges_total``
+        self.link = "chip" if max(self.nb) > 1 else "local"
         # fused-vs-per-phase decision (DESIGN.md "VMEM working-set math"):
         # x-tiling forces per-phase; so does a brick working set beyond the
         # VMEM budget, and the fallback then picks the largest x-tile whose
@@ -396,28 +398,30 @@ class LatticeDSIM:
         up=True: receive the plane of my -1 neighbor (their high face).
         The ONE place the neighbor permutation tables and the k==1
         wrap/zero boundary rule live — both the unpacked (optionally
-        pm1-bitpacked) and the bitplane word exchanges route through it.
+        pm1-bitpacked) and the bitplane word exchanges route through it,
+        so the profile's op metadata names all of it ``lattice.exchange``.
         """
-        if axis_name is None or k == 1:
-            if periodic:
-                return plane  # my own opposite face wraps to me
-            return jnp.zeros_like(plane)
-        if up:
-            perm = [(i, (i + 1) % k) for i in range(k)] if periodic \
-                else [(i, i + 1) for i in range(k - 1)]
-        else:
-            perm = [(i, (i - 1) % k) for i in range(k)] if periodic \
-                else [(i, i - 1) for i in range(1, k)]
-        if not bitpack_pm1:
-            return jax.lax.ppermute(plane, axis_name, perm)
-        shape = plane.shape
-        n = int(np.prod(shape))
-        npad = pad_to_multiple(n, 8)
-        flat = jnp.pad(plane.reshape(-1), (0, npad - n),
-                       constant_values=1)
-        packed = pack_pm1(flat)
-        packed = jax.lax.ppermute(packed, axis_name, perm)
-        return unpack_pm1(packed, n).reshape(shape)
+        with jax.named_scope("lattice.exchange"):
+            if axis_name is None or k == 1:
+                if periodic:
+                    return plane  # my own opposite face wraps to me
+                return jnp.zeros_like(plane)
+            if up:
+                perm = [(i, (i + 1) % k) for i in range(k)] if periodic \
+                    else [(i, i + 1) for i in range(k - 1)]
+            else:
+                perm = [(i, (i - 1) % k) for i in range(k)] if periodic \
+                    else [(i, i - 1) for i in range(1, k)]
+            if not bitpack_pm1:
+                return jax.lax.ppermute(plane, axis_name, perm)
+            shape = plane.shape
+            n = int(np.prod(shape))
+            npad = pad_to_multiple(n, 8)
+            flat = jnp.pad(plane.reshape(-1), (0, npad - n),
+                           constant_values=1)
+            packed = pack_pm1(flat)
+            packed = jax.lax.ppermute(packed, axis_name, perm)
+            return unpack_pm1(packed, n).reshape(shape)
 
     def _exchange_block(self, m):
         """Refresh the six halo planes of this brick via neighbor ppermute.
@@ -1035,6 +1039,7 @@ class LatticeDSIM:
         return lattice_halo_refresh
 
     def _refresh_halos(self, st):
+        exchanges.count(self.link)
         with span("lattice.halo_refresh"):
             halos = self._halo_refresh_fn()(st.m)
             return dataclasses.replace(st, halos=halos)
@@ -1119,9 +1124,13 @@ class LatticeDSIM:
                     return self._run_chunk(iters, S, per_rep)(
                         st, betas2d, self.p.masks, self.p.h, self.p.w6)
 
+        def counted(st, sched2d, iters, S):
+            exchanges.count(self.link, iters)     # one per S sweeps
+            return chunk(st, sched2d, iters, S)
+
         kw = dict(
             state=state, schedule=sched, record_points=record_points,
-            chunk_fn=chunk, record_fn=self.energy, sync_every=int(sync_every),
+            chunk_fn=counted, record_fn=self.energy, sync_every=int(sync_every),
             flips_of=lambda st: st.flips,
             flips_per_sweep=self.n_sites * self.replicas)
         if cursor:

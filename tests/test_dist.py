@@ -153,3 +153,74 @@ def test_sharded_train_step_matches_single_device():
     """)
     assert "LOSS_EQ True" in out
     assert "PARAM_EQ True" in out
+
+
+@pytest.mark.parametrize("devices,link", [(4, "chip"), (1, "local")])
+def test_lattice_exchanges_counted_at_dispatch(devices, link):
+    """``lattice_exchanges_total{link}``: a recorded run of N chunks of
+    ``iters`` exchange periods counts N * iters, plus one for the halo
+    refresh of its fresh state, under ``chip`` when the lattice is split
+    over devices and under ``local`` on one brick."""
+    out = run_py(f"""
+        from repro.compat import auto_axes, make_mesh
+        from repro.core.annealing import ea_schedule
+        from repro.core.lattice import build_ea3d_lattice
+        from repro.engines import make_engine
+        from repro.obs import exchanges
+        fam = exchanges.exchanges().counter(exchanges.EXCHANGES)
+        counts = lambda: {{k: fam.labels(link=k).value
+                          for k in exchanges.LINKS}}
+        mesh = make_mesh(({devices},), ("x",), axis_types=auto_axes(1))
+        h = make_engine("lattice", lattice=build_ea3d_lattice(8, seed=1),
+                        mesh=mesh, dim_axes=("x", None, None),
+                        precision="int8", replicas=2)
+        before = counts()
+        cur = h.start_recorded(h.init_state_packed([1, 2]), ea_schedule(24),
+                               [8, 16, 24], sync_every=4)
+        chunks = 0
+        while not cur.done:
+            chunks += cur.advance(1)
+        after = counts()
+        print("BRICK", h.eng.brick, "CHUNKS", chunks)
+        for k in exchanges.LINKS:
+            print("COUNT", k, after[k] - before[k])
+    """, devices=devices)
+    assert "CHUNKS 3" in out
+    other = "local" if link == "chip" else "chip"
+    assert f"COUNT {link} {3 * 2 + 1}" in out, out
+    assert f"COUNT {other} 0" in out, out
+
+
+def test_exchange_scope_adds_no_op():
+    """``lattice.exchange`` names the exchange in the op metadata and
+    adds no op: the lowered chunk has the same ops without it."""
+    out = run_py("""
+        import collections, contextlib, re
+        import jax
+        from repro.compat import auto_axes, make_mesh
+        from repro.core.lattice import build_ea3d_lattice
+        from repro.core.lattice_dsim import LatticeDSIM
+        mesh = make_mesh((4,), ("x",), axis_types=auto_axes(1))
+        prob = build_ea3d_lattice(8, seed=1)
+
+        def lowered(scope):
+            saved = jax.named_scope
+            if not scope:
+                jax.named_scope = lambda name: contextlib.nullcontext()
+            try:
+                eng = LatticeDSIM(prob, mesh, ("x", None, None),
+                                  precision="int8", replicas=2)
+                return eng.lower_chunk(iters=2, S=4).as_text(
+                    debug_info=True)
+            finally:
+                jax.named_scope = saved
+
+        ops = lambda t: collections.Counter(
+            re.findall(r"\\b(?:stablehlo|chlo|sdy|func)\\.[a-z_]+", t))
+        a, b = lowered(True), lowered(False)
+        print("SAME", ops(a) == ops(b))
+        print("PERMUTES", ops(a)["stablehlo.collective_permute"])
+        print("NAMED", "lattice.exchange" in a, "lattice.exchange" in b)
+    """)
+    assert "SAME True" in out and "NAMED True False" in out, out
+    assert "PERMUTES 0" not in out, out

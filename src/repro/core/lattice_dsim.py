@@ -22,7 +22,10 @@ in the multi-pod dry-run.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import os
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -37,7 +40,7 @@ from .lattice import LatticeProblem
 from .packing import (LANE_WIDTH, pack_lanes, pack_pm1, unpack_lanes,
                       unpack_pm1, pad_to_multiple)
 from .pbit import (FixedPoint, LUT_SELECT_MAX_WIDTH, bitplane_planes,
-                   field_bound, flips_publish, lfsr_init,
+                   field_bound, flips_publish, lfsr_states,
                    quantize_couplings, threshold_lut_cached)
 from repro.compat import shard_map
 from repro.obs import exchanges, programs
@@ -150,6 +153,51 @@ def fused_brick_ceiling(n_colors: int, precision: str = "f32",
             (side, side, side), n_colors, precision, lanes=lanes) > budget:
         side -= 1
     return side
+
+
+_DRAW_WORKERS_MAX = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _pool(workers: int) -> ThreadPoolExecutor:
+    return ThreadPoolExecutor(max_workers=workers,
+                              thread_name_prefix="lattice-draw")
+
+
+# a forked child inherits the pools but not their threads
+os.register_at_fork(after_in_child=_pool.cache_clear)
+
+
+def draw_pool(chains: int) -> Optional[ThreadPoolExecutor]:
+    """The process's pool for drawing ``chains`` chains at once, with
+    ``min(chains, os.cpu_count(), 8)`` workers; None where that is one,
+    and the chains are drawn on the calling thread."""
+    workers = min(int(chains), os.cpu_count() or 1, _DRAW_WORKERS_MAX)
+    return _pool(workers) if workers > 1 else None
+
+
+def draw_chains(seeds: Sequence[int], dims: Tuple[int, int, int]):
+    """Host spins and LFSR states of one chain per seed: (R, X, Y, Z) int8
+    and uint32.  Chain r depends on ``seeds[r]`` alone.  The chains are
+    drawn concurrently (numpy's bounded-integer fill releases the GIL),
+    each from its own generators, straight into its slice of the output."""
+    R, dims = len(seeds), tuple(dims)
+    m = np.empty((R,) + dims, np.int8)
+    s = np.empty((R,) + dims, np.uint32)
+    n = int(np.prod(dims))
+
+    def draw(r):
+        rng = np.random.default_rng(seeds[r])
+        m[r] = rng.choice(np.array([-1, 1], np.int8), size=dims)
+        s[r] = lfsr_states(n, seeds[r]).reshape(dims)
+
+    pool = draw_pool(R)
+    if pool is None:
+        for r in range(R):
+            draw(r)
+    else:
+        list(pool.map(draw, range(R)))
+    return m, s
 
 
 @jax.tree_util.register_dataclass
@@ -358,6 +406,7 @@ class LatticeDSIM:
         self._chunk_cache = {}
         self._energy_fn = None
         self._exchange_only_fn = None
+        self._halo_refresh = None
         # degraded-mode fabric: the six faces are the boundary sources
         self.degrade = DegradePolicy.parse(degrade)
         self.health = MeshHealthMonitor(self.degrade, 6, kind="faces") \
@@ -959,7 +1008,7 @@ class LatticeDSIM:
         exchange a no-fault run would have performed here, so the returned
         halos are bitwise the no-fault trajectory's (verified in tests).
         Clears staleness/freeze on the health monitor."""
-        st = self._refresh_halos(state)
+        st = dataclasses.replace(state, halos=self._refresh_halos(state.m))
         if self.health is not None:
             self.health.on_resync()
         return st
@@ -969,8 +1018,6 @@ class LatticeDSIM:
         """Fresh replicated state.  ``seeds=[...]`` (length R) gives every
         replica its own explicit seed — the packed-batch path, where
         replica r's trajectory depends only on seeds[r]."""
-        p = self.p
-        X, Y, Z = p.dims
         R = self.replicas
         if seeds is not None:
             seeds = [int(s) for s in seeds]
@@ -978,44 +1025,33 @@ class LatticeDSIM:
                 raise ValueError(f"need exactly R={R} seeds, got {len(seeds)}")
         else:
             seeds = [seed] if R == 1 else spawn_seeds(seed, R)
+        bitplane = self.precision == "bitplane"
         with span("lattice.init_state"):
-            ms, ss = [], []
             with span("lattice.init_state.draw"):
-                for sd in seeds:
-                    rng = np.random.default_rng(sd)
-                    ms.append(rng.choice(np.array([-1, 1], np.int8),
-                                         size=(X, Y, Z)))
-                    ss.append(np.asarray(lfsr_init(X * Y * Z, sd))
-                              .reshape(X, Y, Z))
+                m, s = draw_chains(seeds, self.p.dims)
             with span("lattice.init_state.put"):
-                s = jnp.asarray(np.stack(ss))
-                if self.precision == "bitplane":
-                    # lane r's spins and LFSR column come from seeds[r]
-                    # exactly as replica r of the unpacked engines would —
-                    # lane r of a packed run is bit-identical to int8
-                    # replica r at matched schedules
-                    mw = pack_lanes(jnp.asarray(np.stack(ms)))
-                    halos = tuple(jnp.zeros(sh, jnp.uint32)
-                                  for sh in self._halo_shapes())
-                    st = BitplaneLatticeState(
-                        m=mw, s=s, halos=halos,
-                        sweep=jnp.zeros((), jnp.int32),
-                        flips=jnp.zeros((R,), jnp.int32))
-                else:
-                    m = jnp.asarray(np.stack(ms))
-                    halos = tuple(jnp.zeros(sh, jnp.int8)
-                                  for sh in self._halo_shapes())
-                    st = LatticeState(m=m, s=s, halos=halos,
-                                      sweep=jnp.zeros((), jnp.int32),
-                                      flips=jnp.zeros((R,), jnp.int32))
-                st = self.shard_state(st)
+                def put(x, spec=self.spec_m):
+                    return jax.device_put(x, self._shard(spec))
+
+                # each device receives only its own slab of the host buffers
+                s = put(s)
+                # lane r's spins and LFSR column come from seeds[r] exactly
+                # as replica r of the unpacked engines would — lane r of a
+                # packed run is bit-identical to int8 replica r at matched
+                # schedules
+                m = put(pack_lanes(jnp.asarray(m)) if bitplane else m)
+                sweep = put(np.zeros((), np.int32), P())
+                flips = put(np.zeros((R,), np.int32), P())
             # one synchronizing exchange so the first sweeps see real halos
-            return self._refresh_halos(st)
+            cls = BitplaneLatticeState if bitplane else LatticeState
+            return cls(m=m, s=s, halos=self._refresh_halos(m), sweep=sweep,
+                       flips=flips)
 
     def shard_state(self, st):
-        # drop the cached exchange-only closure: it closed over the old
+        # drop the cached exchange closures: they closed over the old
         # sharding, and a restore()/re-shard must not probe stale layouts
         self._exchange_only_fn = None
+        self._halo_refresh = None
         put = jax.device_put
         cls = type(st)
         # bitplane words lead with the W stacked planes, unpacked spins
@@ -1029,20 +1065,23 @@ class LatticeDSIM:
             flips=put(st.flips, self._shard(P())))
 
     def _halo_refresh_fn(self):
-        """A new jitted halo refresh (traced again on every call of it)."""
-        smapped = self._halo_program()
+        """The jitted halo refresh, built once per engine and sharding
+        (``shard_state`` drops it)."""
+        if self._halo_refresh is None:
+            smapped = self._halo_program()
 
-        @jax.jit
-        def lattice_halo_refresh(m):
-            return smapped(m)
+            @jax.jit
+            def lattice_halo_refresh(m):
+                return smapped(m)
 
-        return lattice_halo_refresh
+            self._halo_refresh = lattice_halo_refresh
+        return self._halo_refresh
 
-    def _refresh_halos(self, st):
+    def _refresh_halos(self, m):
+        """Every halo plane of spins ``m``, one exchange."""
         exchanges.count(self.link)
         with span("lattice.halo_refresh"):
-            halos = self._halo_refresh_fn()(st.m)
-            return dataclasses.replace(st, halos=halos)
+            return self._halo_refresh_fn()(m)
 
     def run_recorded_full(self, state: LatticeState, schedule,
                           record_points: Sequence[int], sync_every: int = 1,

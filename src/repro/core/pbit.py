@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["FixedPoint", "quantize", "pbit_update", "lfsr_init", "lfsr_next",
+__all__ = ["FixedPoint", "quantize", "pbit_update", "lfsr_init",
+           "lfsr_states", "lfsr_next",
            "lfsr_uniform", "S41", "S43", "S46",
            "LFSR_UNIFORM_BITS", "quantize_couplings", "field_bound",
            "threshold_lut", "threshold_lut_cached", "lut_accept",
@@ -91,11 +92,16 @@ def pbit_update(field: jnp.ndarray, beta, rand_u: jnp.ndarray,
 # LFSR (xorshift32) — the hardware RNG, vectorized one state per p-bit
 # ---------------------------------------------------------------------------
 
-def lfsr_init(n: int, seed: int) -> jnp.ndarray:
-    """Nonzero uint32 states, seeded reproducibly (host-side splitmix64)."""
+def lfsr_states(n: int, seed: int) -> np.ndarray:
+    """Nonzero uint32 states, seeded reproducibly (host-side splitmix64),
+    as a host array: the one definition of the LFSR seed stream."""
     rng = np.random.default_rng(np.uint64(seed) ^ np.uint64(0x9E3779B97F4A7C15))
-    s = rng.integers(1, 2 ** 32, size=n, dtype=np.uint32)
-    return jnp.asarray(s)
+    return rng.integers(1, 2 ** 32, size=n, dtype=np.uint32)
+
+
+def lfsr_init(n: int, seed: int) -> jnp.ndarray:
+    """:func:`lfsr_states` on the device."""
+    return jnp.asarray(lfsr_states(n, seed))
 
 
 def lfsr_next(state: jnp.ndarray) -> jnp.ndarray:

@@ -8,7 +8,7 @@ reviewed like code.
 
 Waiver file syntax (one per line, ``#`` starts the rationale/comment)::
 
-    AL-DEAD  src/repro/launch/train.py   # CLI entry point, example-driven
+    AL-DEAD  src/repro/launch/dryrun.py  # CLI entry point, run as its own process
     IR-C     ir:dsim_dist/f32/*          # <why this config is exempt>
 
 The location pattern is fnmatch-matched against the finding location with

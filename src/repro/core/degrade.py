@@ -18,9 +18,11 @@ that physics into the machine's failure-handling contract:
   marking, and the ``resync()`` bookkeeping when an engine forces an
   instantaneous full-boundary refresh.
 
-The in-trace side lives in the engines (``core/dsim_dist.py`` /
-``core/lattice_dsim.py``): the health carry is a 6-tuple of replicated
-scalars/vectors threaded through the chunk scan, and held exchanges are
+The in-trace side: the health carry is a 6-tuple of replicated
+scalars/vectors threaded through the chunk scan; :func:`fault_code` and
+:func:`health_step` are the integrity step both mesh engines share, and
+each engine's checked exchange (``core/dsim_dist.py`` /
+``core/lattice_dsim.py``) keeps only its own wire.  Held exchanges are
 ``jnp.where`` selections against the carried (last-known-good) ghosts, so
 a run with zero detections is bitwise identical to an unchecked run.
 
@@ -38,7 +40,8 @@ from typing import ClassVar, Optional, Tuple, Union
 import numpy as np
 
 __all__ = ["StateCorruption", "DegradePolicy", "MeshHealthMonitor",
-           "health_init", "wire_checksum", "wire_words", "DEGRADE_MODES"]
+           "health_init", "fault_code", "health_step", "wire_checksum",
+           "wire_words", "DEGRADE_MODES"]
 
 DEGRADE_MODES = ("fail_fast", "stale_hold", "freeze_boundary")
 
@@ -124,6 +127,36 @@ def health_init(n_sources: int) -> tuple:
     numpy scalars/arrays; jit converts at the boundary."""
     return (np.uint32(0), np.zeros(int(n_sources), np.int32), np.int32(0),
             np.int32(0), np.int32(0), np.int32(0))
+
+
+def fault_code(codes, seq):
+    """(corrupt, drop) flags of the host-scheduled fault at exchange
+    ``seq`` (``codes[seq]``: 0 ok, 1 drop, 2 corrupt; none past the end)."""
+    import jax.numpy as jnp
+    total = jnp.uint32(codes.shape[0])
+    code = jnp.where(seq < total,
+                     codes[jnp.clip(seq, 0, total - 1).astype(jnp.int32)], 0)
+    return code == 2, code == 1
+
+
+def health_step(health, ok, freeze: bool):
+    """One checked exchange's bookkeeping: ``ok`` (n_sources,) says which
+    sources passed the wire check.  Returns (bad, health'): ``bad`` marks
+    the sources to hold at last-known-good (every source once frozen, under
+    ``freeze``), and the carry advances its counters and ``seq``."""
+    import jax.numpy as jnp
+    seq, stale, frozen, det, held, maxst = health
+    if freeze:
+        frozen = jnp.maximum(frozen, (~ok).any().astype(jnp.int32))
+        bad = (~ok) | (frozen > 0)
+    else:
+        bad = ~ok
+    det = det + (~ok).any().astype(jnp.int32)
+    held = held + bad.any().astype(jnp.int32)
+    stale = jnp.where(bad, stale + 1, 0)
+    maxst = jnp.maximum(maxst, stale.max())
+    seq = seq + jnp.uint32(1)
+    return bad, (seq, stale, frozen, det, held, maxst)
 
 
 def wire_words(x):

@@ -43,7 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Sequence, Union
+from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -51,8 +51,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .dsim import PartitionedProblem, DSIMState
-from .degrade import (DegradePolicy, MeshHealthMonitor, health_init,
-                      wire_checksum)
+from .degrade import (DegradePolicy, MeshHealthMonitor, fault_code,
+                      health_init, health_step, wire_checksum)
 from .annealing import ArraySchedule, beta_row_indices, beta_table
 from .pbit import (FixedPoint, bitplane_planes, field_bound, flips_publish,
                    lfsr_init, lfsr_next, lfsr_uniform, lut_accept, quantize,
@@ -465,7 +465,7 @@ class DistDSIMEngine:
         indexed sequence numbers — the engine-boundary fault site; the
         detection below derives only from the wire contents.
         """
-        seq, stale, frozen, det, held, maxst = health
+        seq = health[0]
         K = self.p.K
         word = self.precision == "bitplane"
         lanes = int(m.shape[0])                   # W word planes | R chains
@@ -496,11 +496,7 @@ class DistDSIMEngine:
         if codes is not None:
             # engine-boundary fault injection on the RECEIVED pool: the
             # detection below sees only the (possibly damaged) wire bits
-            total = jnp.uint32(codes.shape[0])
-            code = jnp.where(
-                seq < total,
-                codes[jnp.clip(seq, 0, total - 1).astype(jnp.int32)], 0)
-            corrupt, drop = code == 2, code == 1
+            corrupt, drop = fault_code(codes, seq)
             flip = jnp.asarray(2 if wire.dtype == jnp.int8 else 0x00400000,
                                wire.dtype)
             wire = jnp.where(corrupt, wire ^ flip, wire)
@@ -508,17 +504,7 @@ class DistDSIMEngine:
             hdrs = jnp.where(drop, jnp.full_like(hdrs, 0xFFFFFFFF), hdrs)
         ck_k = jax.vmap(wire_checksum)(wire)                     # (K,)
         ok_k = (ck_k == hdrs[:, 1]) & (hdrs[:, 0] == seq)
-        if freeze:
-            frozen = jnp.maximum(frozen,
-                                 (~ok_k).any().astype(jnp.int32))
-            bad_k = (~ok_k) | (frozen > 0)
-        else:
-            bad_k = ~ok_k
-        det = det + (~ok_k).any().astype(jnp.int32)
-        held = held + bad_k.any().astype(jnp.int32)
-        stale = jnp.where(bad_k, stale + 1, 0)
-        maxst = jnp.maximum(maxst, stale.max())
-        seq = seq + jnp.uint32(1)
+        bad_k, health = health_step(health, ok_k, freeze)
         # ingest per source: held sources keep last-known-good ghosts
         if word:
             vals = wire
@@ -530,138 +516,83 @@ class DistDSIMEngine:
         ghosts_new = pool2[:, consts["ghost_src_pool"]]
         bad_entry = bad_k[consts["ghost_src_part"]]              # (g_max,)
         ghosts = jnp.where(bad_entry[None, :], ghosts_prev, ghosts_new)
-        return ghosts, (seq, stale, frozen, det, held, maxst)
+        return ghosts, health
 
-    def _iteration_block_deg(self, m, ghosts, macc, rng, flips, betas_S,
-                             consts, health, codes, freeze, lut=None):
-        """S sweeps (no inline exchange) + one checked boundary exchange."""
-        if self.precision == "bitplane":
-            m, _, macc, rng, flips = self._iteration_block_w(
-                m, ghosts, macc, rng, flips, betas_S, None, consts, lut)
-        else:
-            m, _, macc, rng, flips = self._iteration_block(
-                m, ghosts, macc, rng, flips, betas_S, None, consts, lut)
-        ghosts, health = self._exchange_block_checked(
-            m, consts, ghosts, health, codes, freeze)
-        return m, ghosts, macc, rng, flips, health
+    # -- runner ---------------------------------------------------------------------
 
-    # -- runners --------------------------------------------------------------------
-
-    def _run_chunk(self, iters: int, S: int, sync: SyncSpec):
-        key = (iters, S, sync)
+    def _run_chunk(self, iters: int, S: int, sync: SyncSpec,
+                   check: Optional[Tuple[bool, bool]] = None):
+        """The jitted chunk of every precision, called as ``(state, betas,
+        consts, *rest)``: ``rest`` is the threshold LUT off the f32 path,
+        then — with ``check = (freeze, has_codes)``, the integrity layer
+        on — the health carry and, with ``has_codes``, the fault codes.
+        Returns the new state, or with ``check`` the (state, health) pair:
+        each iteration then sweeps with no exchange of its own and runs
+        the checked exchange (one per S sweeps; ``sync`` is not read)."""
+        key = (iters, S, sync, check)
         if key in self._chunk_cache:
             return self._chunk_cache[key]
 
         spec_m = P(self.axis)
         cspec = jax.tree.map(lambda _: spec_m, self._consts)
         has_lut = self.precision != "f32"
-        word = self.precision == "bitplane"
+        iteration = self._iteration_block_w \
+            if self.precision == "bitplane" else self._iteration_block
+        freeze, has_codes = check or (False, False)
+        hspec = tuple(P() for _ in range(6))
 
-        def block(m, ghosts, macc, rng, flips_in, betas, consts, *lut_opt):
+        def block(m, ghosts, macc, rng, flips_in, betas, consts, *rest):
             # squeeze the device-local partition dim from state and consts
             m, ghosts, macc, rng = m[0], ghosts[0], macc[0], rng[0]
             consts = jax.tree.map(lambda x: x[0], consts)
-            lut = lut_opt[0] if lut_opt else None
+            lut = rest[0] if has_lut else None
+            codes = rest[-1] if has_codes else None
             # per-chunk flips accumulate in uint32 (modular-exact at any
             # magnitude): the old int32 accumulator overflowed *within* a
             # single long chunk at ~2.1e9 lane-flips, before the psum and
             # the driver's mod-2^32 odometer read ever saw it
-            local = jnp.zeros(flips_in.shape, jnp.uint32)
+            carry = (m, ghosts, macc, rng, jnp.zeros(flips_in.shape,
+                                                      jnp.uint32))
+            if check is not None:
+                carry += (rest[int(has_lut)],)
 
             def it(carry, b):
-                m, ghosts, macc, rng, fl = carry
-                if word:
-                    out = self._iteration_block_w(m, ghosts, macc, rng, fl,
-                                                  b, sync, consts, lut)
-                else:
-                    out = self._iteration_block(m, ghosts, macc, rng, fl, b,
-                                                sync, consts, lut)
-                return out, None
-            (m, ghosts, macc, rng, local), _ = jax.lax.scan(
-                it, (m, ghosts, macc, rng, local), betas)
-            total = jax.lax.psum(local, self.axis)
-            flips = flips_publish(flips_in, total)
-            return m[None], ghosts[None], macc[None], rng[None], flips
-
-        in_specs = (spec_m, spec_m, spec_m, spec_m, P(), P(), cspec)
-        if has_lut:
-            in_specs = in_specs + (P(),)
-        smapped = shard_map(
-            block, mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=(spec_m, spec_m, spec_m, spec_m, P()),
-            check_vma=False,
-        )
-
-        @jax.jit
-        def run(state: DSIMState, betas, consts, *lut_opt):
-            m, ghosts, macc, rng, flips = smapped(
-                state.m, state.ghosts, state.macc, state.rng, state.flips,
-                betas, consts, *lut_opt)
-            return DSIMState(m=m, ghosts=ghosts, macc=macc, rng=rng,
-                             sweep=state.sweep + betas.shape[0] * betas.shape[1],
-                             flips=flips)
-
-        self._chunk_cache[key] = run
-        return run
-
-    def _run_chunk_deg(self, iters: int, S: int, freeze: bool,
-                       has_codes: bool):
-        """Chunk runner with the integrity layer on: threads the health
-        carry through the iteration scan and runs the checked exchange.
-        Needs an integer ``sync_every`` (one exchange per S sweeps)."""
-        key = ("deg", iters, S, freeze, has_codes)
-        if key in self._chunk_cache:
-            return self._chunk_cache[key]
-
-        spec_m = P(self.axis)
-        cspec = jax.tree.map(lambda _: spec_m, self._consts)
-        has_lut = self.precision != "f32"
-        hspec = tuple(P() for _ in range(6))
-
-        def block(m, ghosts, macc, rng, flips_in, betas, consts, health,
-                  *rest):
-            m, ghosts, macc, rng = m[0], ghosts[0], macc[0], rng[0]
-            consts = jax.tree.map(lambda x: x[0], consts)
-            codes = rest[0] if has_codes else None
-            lut = rest[-1] if has_lut else None
-            local = jnp.zeros(flips_in.shape, jnp.uint32)
-
-            def it(carry, b):
+                if check is None:
+                    return iteration(*carry, b, sync, consts, lut), None
                 m, ghosts, macc, rng, fl, health = carry
-                out = self._iteration_block_deg(m, ghosts, macc, rng, fl,
-                                                b, consts, health, codes,
-                                                freeze, lut)
-                return out, None
-            (m, ghosts, macc, rng, local, health), _ = jax.lax.scan(
-                it, (m, ghosts, macc, rng, local, health), betas)
-            total = jax.lax.psum(local, self.axis)
-            flips = flips_publish(flips_in, total)
-            return m[None], ghosts[None], macc[None], rng[None], flips, \
-                health
+                m, _, macc, rng, fl = iteration(m, ghosts, macc, rng, fl, b,
+                                                None, consts, lut)
+                ghosts, health = self._exchange_block_checked(
+                    m, consts, ghosts, health, codes, freeze)
+                return (m, ghosts, macc, rng, fl, health), None
+            carry, _ = jax.lax.scan(it, carry, betas)
+            m, ghosts, macc, rng, local = carry[:5]
+            flips = flips_publish(flips_in, jax.lax.psum(local, self.axis))
+            return (m[None], ghosts[None], macc[None], rng[None], flips) \
+                + carry[5:]
 
-        in_specs = (spec_m, spec_m, spec_m, spec_m, P(), P(), cspec, hspec)
-        if has_codes:
-            in_specs = in_specs + (P(),)
-        if has_lut:
-            in_specs = in_specs + (P(),)
+        in_specs = (spec_m, spec_m, spec_m, spec_m, P(), P(), cspec) \
+            + ((P(),) if has_lut else ()) \
+            + ((hspec,) if check is not None else ()) \
+            + ((P(),) if has_codes else ())
         smapped = shard_map(
             block, mesh=self.mesh,
             in_specs=in_specs,
-            out_specs=(spec_m, spec_m, spec_m, spec_m, P(), hspec),
+            out_specs=(spec_m, spec_m, spec_m, spec_m, P())
+            + ((hspec,) if check is not None else ()),
             check_vma=False,
         )
 
         @jax.jit
-        def run(state: DSIMState, betas, consts, health, *rest):
-            m, ghosts, macc, rng, flips, health = smapped(
+        def run(state: DSIMState, betas, consts, *rest):
+            m, ghosts, macc, rng, flips, *health = smapped(
                 state.m, state.ghosts, state.macc, state.rng, state.flips,
-                betas, consts, health, *rest)
+                betas, consts, *rest)
             st = DSIMState(
                 m=m, ghosts=ghosts, macc=macc, rng=rng,
                 sweep=state.sweep + betas.shape[0] * betas.shape[1],
                 flips=flips)
-            return st, health
+            return (st, health[0]) if check is not None else st
 
         self._chunk_cache[key] = run
         return run
@@ -701,52 +632,36 @@ class DistDSIMEngine:
         ``cursor=True``, the resumable RecordedCursor."""
         sync = sync_every if sync_every in ("phase", None) else int(sync_every)
 
-        deg = self.degrade is not None
-        if deg and sync in ("phase", None):
-            raise ValueError("degrade policies need an integer sync_every "
-                             "(one checked exchange per S sweeps)")
-        if deg:
+        check = None
+        if self.degrade is not None:
+            if sync in ("phase", None):
+                raise ValueError("degrade policies need an integer "
+                                 "sync_every (one checked exchange per S "
+                                 "sweeps)")
             self.health.reset()
             codes = self._fault_codes
-            freeze = self.degrade.mode == "freeze_boundary"
-            has_codes = codes is not None
+            check = (self.degrade.mode == "freeze_boundary",
+                     codes is not None)
+            codes_opt = () if codes is None else (codes,)
 
         if self.precision != "f32":
             # the staircase becomes LUT row indices (beta is in the table)
             beta_arr = np.asarray(schedule.beta_array(), np.float32)
             table = beta_table(beta_arr)
-            lut = self._lut_for(table)
+            lut_opt = (self._lut_for(table),)
             sched = ArraySchedule(beta_row_indices(beta_arr, table))
-
-            if deg:
-                def chunk(st, rows2d, iters, S):
-                    rest = ((codes,) if has_codes else ()) + (lut,)
-                    st, carry = self._run_chunk_deg(
-                        iters, S, freeze, has_codes)(
-                            st, rows2d, self._consts,
-                            self.health.carry, *rest)
-                    self.health.update(carry, exchanges=iters)
-                    return st
-            else:
-                def chunk(st, rows2d, iters, S):
-                    return self._run_chunk(iters, S, sync)(st, rows2d,
-                                                           self._consts, lut)
         else:
-            sched = schedule
+            sched, lut_opt = schedule, ()
 
-            if deg:
-                def chunk(st, betas2d, iters, S):
-                    rest = (codes,) if has_codes else ()
-                    st, carry = self._run_chunk_deg(
-                        iters, S, freeze, has_codes)(
-                            st, betas2d, self._consts,
-                            self.health.carry, *rest)
-                    self.health.update(carry, exchanges=iters)
-                    return st
-            else:
-                def chunk(st, betas2d, iters, S):
-                    return self._run_chunk(iters, S, sync)(st, betas2d,
-                                                           self._consts)
+        def chunk(st, sched2d, iters, S):
+            if check is None:
+                return self._run_chunk(iters, S, sync)(
+                    st, sched2d, self._consts, *lut_opt)
+            st, carry = self._run_chunk(iters, S, sync, check=check)(
+                st, sched2d, self._consts, *lut_opt, self.health.carry,
+                *codes_opt)
+            self.health.update(carry, exchanges=iters)
+            return st
 
         kw = dict(
             state=state, schedule=sched, record_points=record_points,
@@ -822,8 +737,8 @@ class DistDSIMEngine:
                     has_codes: bool = False):
         """(runner, abstract args) for one sampling chunk — shared by the
         lowering dry-run and the static contract auditor's tracer.  With
-        ``degrade`` the checked-exchange runner (health carry, optional
-        fault-code operand) is selected instead of the plain one."""
+        ``degrade`` the runner runs checked (health carry, optional
+        fault-code operand)."""
         p, R = self.p, self.replicas
 
         def sds(x, shard):
@@ -868,18 +783,17 @@ class DistDSIMEngine:
         lut_opt = () if self.precision == "f32" else (
             jax.ShapeDtypeStruct((1, 2 * self.f_max + 1), jnp.uint32,
                                  sharding=self._repl),)
+        args = (st, sched, consts) + lut_opt
         if not degrade:
-            return self._run_chunk(iters, S, sync), \
-                (st, sched, consts) + lut_opt
-        health = tuple(
-            jax.ShapeDtypeStruct(np.shape(h), np.asarray(h).dtype,
-                                 sharding=self._repl)
-            for h in health_init(p.K))
-        codes_opt = (jax.ShapeDtypeStruct((8,), jnp.uint32,
-                                          sharding=self._repl),) \
-            if has_codes else ()
-        run = self._run_chunk_deg(iters, S, freeze, has_codes)
-        return run, (st, sched, consts, health) + codes_opt + lut_opt
+            return self._run_chunk(iters, S, sync), args
+        args += (tuple(jax.ShapeDtypeStruct(np.shape(h), np.asarray(h).dtype,
+                                            sharding=self._repl)
+                       for h in health_init(p.K)),)
+        if has_codes:
+            args += (jax.ShapeDtypeStruct((8,), jnp.uint32,
+                                          sharding=self._repl),)
+        return self._run_chunk(iters, S, sync, check=(freeze, has_codes)), \
+            args
 
     def lower_chunk(self, iters: int = 4, S: int = 4, sync: SyncSpec = 4):
         """Lower (not run) one sampling chunk — used by the launch dry-run."""

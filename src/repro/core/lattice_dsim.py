@@ -34,8 +34,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .annealing import ArraySchedule, beta_row_indices, beta_table
-from .degrade import (DegradePolicy, MeshHealthMonitor, health_init,
-                      wire_checksum)
+from .degrade import (DegradePolicy, MeshHealthMonitor, fault_code,
+                      health_init, health_step, wire_checksum)
 from .lattice import LatticeProblem
 from .packing import (LANE_WIDTH, pack_lanes, pack_pm1, unpack_lanes,
                       unpack_pm1, pad_to_multiple)
@@ -71,6 +71,25 @@ __all__ = ["LatticeDSIM", "LatticeState", "BitplaneLatticeState",
 # what the static auditor (rule IR-F) compares against.
 DEFAULT_VMEM_BUDGET = 16 << 20  # 16 MiB/core, the TPU VMEM working budget
 _LANES = 128
+
+# The six halo faces in state order, (lattice dim, up, periodic): up=True
+# receives the -1 neighbor's high face (my low halo).  x and y are open
+# chains, z is a periodic ring.
+_FACES = ((0, True, False), (0, False, False), (1, True, False),
+          (1, False, False), (2, True, True), (2, False, True))
+
+
+def _drop(plane, d):
+    """A face plane without its one-wide lattice dim ``d`` (the leading
+    replica or word axis kept)."""
+    return plane[(slice(None),) * (d + 1) + (0,)]
+
+
+def _lift_all(halos):
+    """Six squeezed halo planes back into the state's layout, each with
+    its one-wide lattice dim."""
+    return tuple(x[(slice(None),) * (d + 1) + (None,)]
+                 for x, (d, _, _) in zip(halos, _FACES))
 
 
 def _round_up(n: int, m: int) -> int:
@@ -308,24 +327,6 @@ class LatticeDSIM:
         else:
             self.h_q = self.w6_q = None
             self.q_scale, self.f_max = 1.0, 0
-        if precision == "bitplane":
-            # sign-plane quantization (validates couplings land on +-1/0)
-            # + lane-masked uint32 color masks: lanes >= R never update.
-            # Dead lanes live only in the LAST word plane, so every other
-            # plane carries the full 32-lane mask.
-            self.signs6_w, self.nz6_w, self.base_w, _ = bitplane_planes(
-                self.h_q, self.w6_q)
-            W = self.words
-            last = self.replicas - (W - 1) * LANE_WIDTH
-            lane_masks = np.full((W,), 0xFFFFFFFF, np.uint64)
-            lane_masks[-1] = (np.uint64(1) << np.uint64(last)) - \
-                np.uint64(1) if last < LANE_WIDTH else np.uint64(0xFFFFFFFF)
-            self.lane_masks = lane_masks.astype(np.uint32)
-            mk = np.asarray(prob.masks)          # (n_colors, X, Y, Z)
-            self.masks_w = jnp.asarray(
-                np.where(mk[:, None] != 0,
-                         self.lane_masks[None, :, None, None, None],
-                         0).astype(np.uint32))   # (n_colors, W, X, Y, Z)
         self._lut_cache = {}
         self.nb = tuple(1 if a is None else mesh.shape[a] for a in dim_axes)
         for d, (ext, k) in enumerate(zip(prob.dims, self.nb)):
@@ -393,15 +394,12 @@ class LatticeDSIM:
         ax, ay, az = dim_axes
         self.spec_m = P(None, ax, ay, az)        # leading replica axis
         self.spec_flat = P(ax, ay, az)           # problem constants (no R)
-        self.spec_masks = P(None, ax, ay, az)
-        # bitplane color masks carry (n_colors, W, X, Y, Z) — two
-        # replicated leading axes ahead of the lattice dims
-        self.spec_masks_w = P(None, None, ax, ay, az)
         # halo plane specs: (R, nbx, Y, Z), ... each sharded so every device
         # holds exactly its (1-plane) halo slice for all replicas.  On the
         # bitplane path the replica axis lives inside the words and the
         # leading axis is the W stacked word planes.
         self.halo_specs = tuple(P(None, ax, ay, az) for _ in range(6))
+        self._field, self._field_specs = self._field_operands()
         self._shard = lambda spec: NamedSharding(mesh, spec)
         self._chunk_cache = {}
         self._energy_fn = None
@@ -426,6 +424,39 @@ class LatticeDSIM:
         return threshold_lut_cached(self._lut_cache, table, self.q_scale,
                                     self.f_max, fmt=self.fmt)
 
+    def _field_operands(self):
+        """The chunk's field operands ``(masks, h, w6)`` and their specs,
+        in this precision's representation: f32 couplings, int8
+        couplings, or on the bitplane path ``(masks_w, base_w, (signs6_w,
+        nz6_w))`` — lane-masked uint32 color masks, the LUT-column base and
+        the per-direction sign/nonzero word planes."""
+        p = self.p
+        flat6 = tuple(self.spec_flat for _ in range(6))
+        if self.precision != "bitplane":
+            h, w6 = (self.h_q, self.w6_q) if self.precision == "int8" \
+                else (p.h, p.w6)
+            return ((p.masks, h, tuple(w6)),
+                    (self.spec_m, self.spec_flat, flat6))
+        # sign-plane quantization (validates couplings land on +-1/0)
+        # + lane-masked uint32 color masks: lanes >= R never update.
+        # Dead lanes live only in the LAST word plane, so every other
+        # plane carries the full 32-lane mask.
+        signs6, nz6, base, _ = bitplane_planes(self.h_q, self.w6_q)
+        W = self.words
+        last = self.replicas - (W - 1) * LANE_WIDTH
+        lane_masks = np.full((W,), 0xFFFFFFFF, np.uint64)
+        lane_masks[-1] = (np.uint64(1) << np.uint64(last)) - \
+            np.uint64(1) if last < LANE_WIDTH else np.uint64(0xFFFFFFFF)
+        mk = np.asarray(p.masks)                 # (n_colors, X, Y, Z)
+        masks_w = jnp.asarray(
+            np.where(mk[:, None] != 0,
+                     lane_masks.astype(np.uint32)[None, :, None, None, None],
+                     0).astype(np.uint32))       # (n_colors, W, X, Y, Z)
+        ax, ay, az = self.dim_axes
+        # the color masks carry two replicated leading axes (n_colors, W)
+        return ((masks_w, base, (tuple(signs6), tuple(nz6))),
+                (P(None, None, ax, ay, az), self.spec_flat, (flat6, flat6)))
+
     # -- halo plumbing -------------------------------------------------------------
 
     def _halo_shapes(self):
@@ -446,9 +477,9 @@ class LatticeDSIM:
 
         up=True: receive the plane of my -1 neighbor (their high face).
         The ONE place the neighbor permutation tables and the k==1
-        wrap/zero boundary rule live — both the unpacked (optionally
-        pm1-bitpacked) and the bitplane word exchanges route through it,
-        so the profile's op metadata names all of it ``lattice.exchange``.
+        wrap/zero boundary rule live — the plain and the checked
+        exchanges of every representation route through it, so the
+        profile's op metadata names all of it ``lattice.exchange``.
         """
         with jax.named_scope("lattice.exchange"):
             if axis_name is None or k == 1:
@@ -472,54 +503,34 @@ class LatticeDSIM:
             packed = jax.lax.ppermute(packed, axis_name, perm)
             return unpack_pm1(packed, n).reshape(shape)
 
+    def _faces(self, m):
+        """The six outgoing face planes of brick ``m``, in halo order:
+        yields ``(plane, axis name, k, up, periodic, d)`` per face, the
+        plane keeping its one-wide lattice dim ``d`` (see ``_FACES``)."""
+        for d, up, periodic in _FACES:
+            edge = slice(-1, None) if up else slice(None, 1)
+            yield (m[(slice(None),) * (d + 1) + (edge,)], self.dim_axes[d],
+                   self.nb[d], up, periodic, d)
+
     def _exchange_block(self, m):
         """Refresh the six halo planes of this brick via neighbor ppermute.
 
-        ``m`` is (R, bx, by, bz); all R planes of one face cross the link in
-        one (1-bit packed) ppermute.  Padding spins in the packed tail are
-        inert (their couplings are zero)."""
-        ax, ay, az = self.dim_axes
-        kx, ky, kz = self.nb
-
-        def shift(plane, axis_name, k, up, periodic):
-            return self._halo_shift(plane, axis_name, k, up, periodic,
-                                    bitpack_pm1=self.bitpack_halos)
-
-        xlo = shift(m[:, -1:, :, :], ax, kx, True, False)[:, 0]
-        xhi = shift(m[:, :1, :, :], ax, kx, False, False)[:, 0]
-        ylo = shift(m[:, :, -1:, :], ay, ky, True, False)[:, :, 0, :]
-        yhi = shift(m[:, :, :1, :], ay, ky, False, False)[:, :, 0, :]
-        zlo = shift(m[:, :, :, -1:], az, kz, True, True)[:, :, :, 0]
-        zhi = shift(m[:, :, :, :1], az, kz, False, True)[:, :, :, 0]
-        return (xlo, xhi, ylo, yhi, zlo, zhi)
-
-    def _exchange_block_w(self, mw):
-        """Bitplane halo exchange: the face slices of the word brick ARE
-        the packed wire format — 1 bit per boundary p-bit per lane, exactly
-        the paper's traffic, with zero pack/unpack compute.  ``mw`` is
-        (W, bx, by, bz): one ppermute ships all W word planes of a face
-        (4 B/site *per word plane*); at R=32 the payload is 8x smaller
-        than the int8 path's unpacked planes.  Boundary words of
-        zero-coupling directions are inert (the nonzero masks zero them)."""
-        ax, ay, az = self.dim_axes
-        kx, ky, kz = self.nb
-
-        def shift(plane, axis_name, k, up, periodic):
-            return self._halo_shift(plane, axis_name, k, up, periodic,
-                                    bitpack_pm1=False)
-
-        xlo = shift(mw[:, -1:, :, :], ax, kx, True, False)[:, 0]
-        xhi = shift(mw[:, :1, :, :], ax, kx, False, False)[:, 0]
-        ylo = shift(mw[:, :, -1:, :], ay, ky, True, False)[:, :, 0, :]
-        yhi = shift(mw[:, :, :1, :], ay, ky, False, False)[:, :, 0, :]
-        zlo = shift(mw[:, :, :, -1:], az, kz, True, True)[:, :, :, 0]
-        zhi = shift(mw[:, :, :, :1], az, kz, False, True)[:, :, :, 0]
-        return (xlo, xhi, ylo, yhi, zlo, zhi)
+        ``m`` is (R, bx, by, bz) spins — all R planes of one face cross
+        the link in one (1-bit packed) ppermute; padding spins in the
+        packed tail are inert (their couplings are zero) — or (W, bx, by,
+        bz) bitplane words, whose face slices already are the 1-bit wire
+        format (one bit per boundary p-bit per lane) and ship unpacked.
+        Boundary words of zero-coupling directions are inert."""
+        bitpack = self.bitpack_halos and self.precision != "bitplane"
+        return tuple(
+            _drop(self._halo_shift(plane, a, k, up, periodic,
+                                   bitpack_pm1=bitpack), d)
+            for plane, a, k, up, periodic, d in self._faces(m))
 
     def boundary_exchange_fn(self):
         """Jitted exchange-ONLY closure: the six-face halo ppermute of
-        ``_exchange_block`` / ``_exchange_block_w`` with the sweep elided.
-        ``fn(state) -> halos`` on live state — the measured-η probe
+        ``_exchange_block`` with the sweep elided.  ``fn(state) -> halos``
+        on live state — the measured-η probe
         (``obs.EtaMeter.measure_exchange`` times it to get t_exchange)."""
         cached = getattr(self, "_exchange_only_fn", None)
         if cached is not None:
@@ -537,73 +548,57 @@ class LatticeDSIM:
     def _halo_program(self):
         """The six-face halo exchange of the spins alone, as a fresh
         ``shard_map``: ``m -> halos`` in the state's halo layout."""
-        exchange = self._exchange_block_w if self.precision == "bitplane" \
-            else self._exchange_block
-
-        def block(m):
-            xlo, xhi, ylo, yhi, zlo, zhi = exchange(m)
-            return (xlo[:, None], xhi[:, None],
-                    ylo[:, :, None, :], yhi[:, :, None, :],
-                    zlo[:, :, :, None], zhi[:, :, :, None])
-
-        return shard_map(block, mesh=self.mesh, in_specs=(self.spec_m,),
+        return shard_map(lambda m: _lift_all(self._exchange_block(m)),
+                         mesh=self.mesh, in_specs=(self.spec_m,),
                          out_specs=self.halo_specs, check_vma=False)
 
     # -- block step -------------------------------------------------------------------
 
-    def _sweep_phases_block(self, m, s, halos, betas_S, masks, h, w6):
+    def _sweep_phases_block(self, m, s, sched_S, update):
         """S sweeps of one replica's brick via per-phase dispatch (the
-        reference path).  m/s (bx, by, bz)."""
-        def body(carry, beta):
+        reference path): ``update(m, s, b, c) -> (m, s)`` is one color
+        phase ``c`` at schedule entry ``b``.  m/s (bx, by, bz)."""
+        def body(carry, b):
             m, s, fl = carry
             for c in range(self.p.n_colors):
-                m2, s = pbit_update_op(m, s, beta, masks[c], h, w6, halos,
-                                       fmt=self.fmt, bx=self.kernel_bx,
-                                       impl=self.impl)
+                m2, s = update(m, s, b, c)
                 fl = fl + (m2 != m).sum().astype(jnp.int32)
                 m = m2
             return (m, s, fl), None
         (m, s, fl), _ = jax.lax.scan(
-            body, (m, s, jnp.zeros((), jnp.int32)), betas_S)
-        return m, s, fl
-
-    def _sweep_phases_int_block(self, m, s, halos, rows_S, masks, h_q, w6_q,
-                                lut):
-        """Integer-path per-phase dispatch: LUT row indices replace betas."""
-        def body(carry, row):
-            m, s, fl = carry
-            for c in range(self.p.n_colors):
-                m2, s = pbit_update_int_op(m, s, row, masks[c], h_q, w6_q,
-                                           halos, lut, bx=self.kernel_bx,
-                                           impl=self.impl)
-                fl = fl + (m2 != m).sum().astype(jnp.int32)
-                m = m2
-            return (m, s, fl), None
-        (m, s, fl), _ = jax.lax.scan(
-            body, (m, s, jnp.zeros((), jnp.int32)), rows_S)
+            body, (m, s, jnp.zeros((), jnp.int32)), sched_S)
         return m, s, fl
 
     def _one_replica_sweeps(self, masks, h, w6, lut):
         """(m, s, halos, sched_S) -> (m, s, flips) for one replica's brick:
         fused or per-phase, float betas or integer LUT rows."""
         if self.precision == "int8":
-            if self.fused:
-                return lambda mr, sr, hr, ps: pbit_sweep_int_op(
-                    mr, sr, ps, masks, h, w6, hr, lut, impl=self.impl)
-            return lambda mr, sr, hr, ps: self._sweep_phases_int_block(
-                mr, sr, hr, ps, masks, h, w6, lut)
+            sweep, update = pbit_sweep_int_op, pbit_update_int_op
+            lut_opt, kw = (lut,), dict(impl=self.impl)
+        else:
+            sweep, update = pbit_sweep_op, pbit_update_op
+            lut_opt, kw = (), dict(fmt=self.fmt, impl=self.impl)
         if self.fused:
-            return lambda mr, sr, hr, ps: pbit_sweep_op(
-                mr, sr, ps, masks, h, w6, hr, fmt=self.fmt, impl=self.impl)
+            return lambda mr, sr, hr, ps: sweep(mr, sr, ps, masks, h, w6, hr,
+                                                *lut_opt, **kw)
         return lambda mr, sr, hr, ps: self._sweep_phases_block(
-            mr, sr, hr, ps, masks, h, w6)
+            mr, sr, ps, lambda m, s, b, c: update(
+                m, s, b, masks[c], h, w6, hr, *lut_opt, bx=self.kernel_bx,
+                **kw))
 
     def _sweep_block(self, m, s, halos, sched_S, masks, h, w6, lut=None):
         """S sweeps for all R replicas against fixed halos (no exchange).
 
         m/s (R, bx, by, bz); halos 6 x (R, plane).  ``sched_S`` is the
         per-sweep schedule — (S,) shared or (S, R) per-replica; f32 betas on
-        the float path, int32 LUT row indices on the integer path."""
+        the float path, int32 LUT row indices on the integer paths.  On the
+        bitplane path m and the halos lead with W word planes, ``masks, h,
+        w6`` are ``(masks_w, base_w, (signs6_w, nz6_w))``, and the word op
+        sweeps every lane at once."""
+        if self.precision == "bitplane":
+            signs, nz = w6
+            return pbit_bitplane_sweep_op(m, s, sched_S, masks, signs, nz, h,
+                                          halos, lut, impl=self.impl)
         one = self._one_replica_sweeps(masks, h, w6, lut)
         per_rep = sched_S.ndim == 2
         from repro.kernels.ops import default_impl
@@ -649,41 +644,21 @@ class LatticeDSIM:
         carried inside the chunk scan).  Health carries per-face staleness;
         per-device divergence (edges) is pmax-reduced at chunk end.
         """
-        seq, stale, frozen, det, held, maxst = health
-        ax, ay, az = self.dim_axes
-        kx, ky, kz = self.nb
+        seq = health[0]
         word = self.precision == "bitplane"
         bitpack = (not word) and self.bitpack_halos
-
-        faces = [
-            (m[:, -1:, :, :], ax, kx, True, False),    # xlo <- -x neighbor
-            (m[:, :1, :, :], ax, kx, False, False),    # xhi <- +x neighbor
-            (m[:, :, -1:, :], ay, ky, True, False),
-            (m[:, :, :1, :], ay, ky, False, False),
-            (m[:, :, :, -1:], az, kz, True, True),     # z is a periodic ring
-            (m[:, :, :, :1], az, kz, False, True),
-        ]
-        squeeze = (lambda p: p[:, 0], lambda p: p[:, 0],
-                   lambda p: p[:, :, 0, :], lambda p: p[:, :, 0, :],
-                   lambda p: p[:, :, :, 0], lambda p: p[:, :, :, 0])
-
-        corrupt = drop = None
         if codes is not None:
-            total = jnp.uint32(codes.shape[0])
-            code = jnp.where(
-                seq < total,
-                codes[jnp.clip(seq, 0, total - 1).astype(jnp.int32)], 0)
-            corrupt, drop = code == 2, code == 1
+            corrupt, drop = fault_code(codes, seq)
 
         new_faces, oks = [], []
-        for i, (plane, axis_name, k, up, periodic) in enumerate(faces):
+        for plane, axis_name, k, up, periodic, d in self._faces(m):
             wired = axis_name is not None and k > 1
             if not wired:
                 # no link: periodic k==1 wraps my own face, open k==1 is a
                 # fixed zero boundary — nothing to verify
                 rx = self._halo_shift(plane, axis_name, k, up, periodic,
                                       bitpack_pm1=False)
-                new_faces.append(squeeze[i](rx))
+                new_faces.append(_drop(rx, d))
                 oks.append(jnp.bool_(True))
                 continue
             rx = self._halo_shift(plane, axis_name, k, up, periodic,
@@ -694,7 +669,7 @@ class LatticeDSIM:
             idx = jax.lax.axis_index(axis_name)
             has_src = jnp.bool_(True) if periodic else \
                 (idx > 0 if up else idx < k - 1)
-            if corrupt is not None:
+            if codes is not None:
                 hit, dr = corrupt & has_src, drop & has_src
                 flip = jnp.uint32(1) if word else jnp.int8(2)
                 rx = jnp.where(hit, rx ^ flip, rx)
@@ -703,22 +678,12 @@ class LatticeDSIM:
                                    hdr_rx)
             ok = (wire_checksum(rx) == hdr_rx[1]) & (hdr_rx[0] == seq)
             oks.append(ok | ~has_src)
-            new_faces.append(squeeze[i](rx))
+            new_faces.append(_drop(rx, d))
 
-        ok6 = jnp.stack(oks)
-        if freeze:
-            frozen = jnp.maximum(frozen, (~ok6).any().astype(jnp.int32))
-            bad6 = (~ok6) | (frozen > 0)
-        else:
-            bad6 = ~ok6
-        det = det + (~ok6).any().astype(jnp.int32)
-        held = held + bad6.any().astype(jnp.int32)
-        stale = jnp.where(bad6, stale + 1, 0)
-        maxst = jnp.maximum(maxst, stale.max())
-        seq = seq + jnp.uint32(1)
+        bad6, health = health_step(health, jnp.stack(oks), freeze)
         halos = tuple(jnp.where(bad6[i], halos_prev[i], new_faces[i])
                       for i in range(6))
-        return halos, (seq, stale, frozen, det, held, maxst)
+        return halos, health
 
     @staticmethod
     def _health_pmax(health, axes_all):
@@ -731,258 +696,80 @@ class LatticeDSIM:
         pm = lambda x: jax.lax.pmax(x, axes_all)  # noqa: E731
         return (seq, pm(stale), pm(frozen), pm(det), pm(held), pm(maxst))
 
-    # -- runners ------------------------------------------------------------------------
+    # -- runner ------------------------------------------------------------------------
 
     def _axes_all(self):
         return tuple(a for a in self.dim_axes if a is not None)
 
-    def _run_chunk(self, iters: int, S: int, per_rep: bool = False):
-        key = (iters, S, per_rep)
+    def _run_chunk(self, iters: int, S: int, per_rep: bool = False,
+                   check: Optional[Tuple[bool, bool]] = None):
+        """The jitted chunk of every representation: ``iters`` x (S sweeps,
+        one halo exchange), called as ``(state, sched, field, *rest)``.
+        ``field`` is the engine's field operands (``_field_operands``);
+        ``rest`` is the threshold LUT on the integer paths, then — with
+        ``check = (freeze, has_codes)``, the integrity layer on — the
+        health carry and, with ``has_codes``, the fault codes.  Returns the
+        new state, or with ``check`` the (state, health) pair: the scan
+        then threads the carry and runs the checked exchange in place of
+        the plain one."""
+        key = (iters, S, per_rep, check)
         if key in self._chunk_cache:
             return self._chunk_cache[key]
-        spec_m, spec_masks = self.spec_m, self.spec_masks
-        spec_flat = self.spec_flat
-        hspecs = self.halo_specs
+        spec_m, hspecs = self.spec_m, self.halo_specs
         axes_all = self._axes_all()
         R = self.replicas
-        int8 = self.precision == "int8"
-
-        def block(m, s, halos, sched, masks, h, w6, lut):
-            # halos arrive as (R, k?, ...) plane stacks; squeeze the brick dims
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, 0], xhi[:, 0], ylo[:, :, 0, :], yhi[:, :, 0, :],
-                     zlo[:, :, :, 0], zhi[:, :, :, 0])
-            local = jnp.zeros((R,), jnp.uint32)
-
-            def it(carry, b):
-                m, s, halos, fl = carry
-                m, s, halos, f = self._iteration_block(m, s, halos, b,
-                                                       masks, h, w6, lut)
-                return (m, s, halos, fl + f.astype(jnp.uint32)), None
-            (m, s, halos, local), _ = jax.lax.scan(
-                it, (m, s, halos, local), sched)
-            flips = jax.lax.psum(local, axes_all) if axes_all else local
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, None], xhi[:, None],
-                     ylo[:, :, None, :], yhi[:, :, None, :],
-                     zlo[:, :, :, None], zhi[:, :, :, None])
-            return m, s, halos, flips
-
-        # identical construction for both precisions — the integer path just
-        # appends the (replicated) threshold LUT as a trailing operand
-        fn = block if int8 else (
-            lambda m, s, halos, sched, masks, h, w6:
-                block(m, s, halos, sched, masks, h, w6, None))
-        lut_spec = ((P(),) if int8 else ())
-        smapped = shard_map(
-            fn, mesh=self.mesh,
-            in_specs=(spec_m, spec_m, hspecs, P(), spec_masks, spec_flat,
-                      tuple(spec_flat for _ in range(6))) + lut_spec,
-            out_specs=(spec_m, spec_m, hspecs, P()),
-            check_vma=False,
-        )
-
-        @jax.jit
-        def lattice_chunk(state: LatticeState, sched, masks, h, w6,
-                          *lut_opt):
-            m, s, halos, fl = smapped(state.m, state.s, state.halos,
-                                      sched, masks, h, w6, *lut_opt)
-            return LatticeState(
-                m=m, s=s, halos=halos,
-                sweep=state.sweep + sched.shape[0] * sched.shape[1],
-                flips=flips_publish(state.flips, fl))
-
-        self._chunk_cache[key] = lattice_chunk
-        return lattice_chunk
-
-    def _run_chunk_bp(self, iters: int, S: int):
-        """Bitplane chunk runner: words sweep via the multi-spin-coded op;
-        halos are native word planes (the 1-bit wire format).  Shared-vs-
-        per-lane schedules need no flag here: the sweep op dispatches on
-        the trailing dims of the rows operand (jit retraces per shape)."""
-        key = ("bp", iters, S)
-        if key in self._chunk_cache:
-            return self._chunk_cache[key]
-        spec_w, spec_m = self.spec_m, self.spec_m
-        spec_masks, spec_flat = self.spec_masks_w, self.spec_flat
-        hspecs = self.halo_specs
-        axes_all = self._axes_all()
-        R = self.replicas
-
-        def block(mw, s, halos, sched, masks_w, signs, nz, base, lut):
-            # halos arrive as (W, k?, ...) plane stacks; squeeze brick dims
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, 0], xhi[:, 0], ylo[:, :, 0, :], yhi[:, :, 0, :],
-                     zlo[:, :, :, 0], zhi[:, :, :, 0])
-            local = jnp.zeros((R,), jnp.uint32)
-
-            def it(carry, b):
-                mw, s, halos, fl = carry
-                mw, s, f = pbit_bitplane_sweep_op(
-                    mw, s, b, masks_w, signs, nz, base, halos, lut,
-                    impl=self.impl)
-                halos = self._exchange_block_w(mw)
-                return (mw, s, halos, fl + f.astype(jnp.uint32)), None
-            (mw, s, halos, local), _ = jax.lax.scan(
-                it, (mw, s, halos, local), sched)
-            flips = jax.lax.psum(local, axes_all) if axes_all else local
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, None], xhi[:, None],
-                     ylo[:, :, None, :], yhi[:, :, None, :],
-                     zlo[:, :, :, None], zhi[:, :, :, None])
-            return mw, s, halos, flips
-
-        smapped = shard_map(
-            block, mesh=self.mesh,
-            in_specs=(spec_w, spec_m, hspecs, P(), spec_masks,
-                      tuple(spec_flat for _ in range(6)),
-                      tuple(spec_flat for _ in range(6)), spec_flat, P()),
-            out_specs=(spec_w, spec_m, hspecs, P()),
-            check_vma=False,
-        )
-
-        @jax.jit
-        def lattice_chunk(state: BitplaneLatticeState, sched, masks_w,
-                          signs, nz, base, lut):
-            mw, s, halos, fl = smapped(state.m, state.s, state.halos,
-                                       sched, masks_w, signs, nz, base, lut)
-            return BitplaneLatticeState(
-                m=mw, s=s, halos=halos,
-                sweep=state.sweep + sched.shape[0] * sched.shape[1],
-                flips=flips_publish(state.flips, fl))
-
-        self._chunk_cache[key] = lattice_chunk
-        return lattice_chunk
-
-    def _run_chunk_deg(self, iters: int, S: int, per_rep: bool,
-                       freeze: bool, has_codes: bool):
-        """int8/f32 chunk runner with the integrity layer on: threads the
-        health carry through the scan and runs the checked exchange."""
-        key = ("deg", iters, S, per_rep, freeze, has_codes)
-        if key in self._chunk_cache:
-            return self._chunk_cache[key]
-        spec_m, spec_masks = self.spec_m, self.spec_masks
-        spec_flat = self.spec_flat
-        hspecs = self.halo_specs
-        axes_all = self._axes_all()
-        R = self.replicas
-        int8 = self.precision == "int8"
+        has_lut = self.precision != "f32"
+        freeze, has_codes = check or (False, False)
         hlspec = tuple(P() for _ in range(6))
 
-        def block(m, s, halos, sched, masks, h, w6, health, *rest):
-            codes = rest[0] if has_codes else None
-            lut = rest[-1] if int8 else None
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, 0], xhi[:, 0], ylo[:, :, 0, :], yhi[:, :, 0, :],
-                     zlo[:, :, :, 0], zhi[:, :, :, 0])
-            local = jnp.zeros((R,), jnp.uint32)
+        def block(m, s, halos, sched, field, *rest):
+            lut = rest[0] if has_lut else None
+            codes = rest[-1] if has_codes else None
+            # halos arrive as (R, k?, ...) plane stacks; squeeze the brick dims
+            halos = tuple(_drop(x, d) for x, (d, _, _) in zip(halos, _FACES))
+            carry = (m, s, halos, jnp.zeros((R,), jnp.uint32))
+            if check is not None:
+                carry += (rest[int(has_lut)],)
 
             def it(carry, b):
+                if check is None:
+                    m, s, halos, fl = carry
+                    m, s, halos, f = self._iteration_block(m, s, halos, b,
+                                                           *field, lut)
+                    return (m, s, halos, fl + f.astype(jnp.uint32)), None
                 m, s, halos, fl, health = carry
-                m, s, f = self._sweep_block(m, s, halos, b, masks, h, w6,
-                                            lut)
+                m, s, f = self._sweep_block(m, s, halos, b, *field, lut)
                 halos, health = self._exchange_block_checked(
                     m, halos, health, codes, freeze)
                 return (m, s, halos, fl + f.astype(jnp.uint32), health), None
-            (m, s, halos, local, health), _ = jax.lax.scan(
-                it, (m, s, halos, local, health), sched)
+            carry, _ = jax.lax.scan(it, carry, sched)
+            m, s, halos, local = carry[:4]
             flips = jax.lax.psum(local, axes_all) if axes_all else local
-            health = self._health_pmax(health, axes_all)
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, None], xhi[:, None],
-                     ylo[:, :, None, :], yhi[:, :, None, :],
-                     zlo[:, :, :, None], zhi[:, :, :, None])
-            return m, s, halos, flips, health
+            out = (m, s, _lift_all(halos), flips)
+            if check is not None:
+                out += (self._health_pmax(carry[4], axes_all),)
+            return out
 
-        in_specs = (spec_m, spec_m, hspecs, P(), spec_masks, spec_flat,
-                    tuple(spec_flat for _ in range(6)), hlspec)
-        if has_codes:
-            in_specs = in_specs + (P(),)
-        if int8:
-            in_specs = in_specs + (P(),)
+        in_specs = (spec_m, spec_m, hspecs, P(), self._field_specs) \
+            + ((P(),) if has_lut else ()) \
+            + ((hlspec,) if check is not None else ()) \
+            + ((P(),) if has_codes else ())
         smapped = shard_map(
             block, mesh=self.mesh, in_specs=in_specs,
-            out_specs=(spec_m, spec_m, hspecs, P(), hlspec),
+            out_specs=(spec_m, spec_m, hspecs, P())
+            + ((hlspec,) if check is not None else ()),
             check_vma=False,
         )
 
         @jax.jit
-        def lattice_chunk(state: LatticeState, sched, masks, h, w6, health,
-                          *rest):
-            m, s, halos, fl, health = smapped(
-                state.m, state.s, state.halos, sched, masks, h, w6,
-                health, *rest)
-            st = LatticeState(
+        def lattice_chunk(state, sched, field, *rest):
+            m, s, halos, fl, *health = smapped(state.m, state.s, state.halos,
+                                               sched, field, *rest)
+            st = type(state)(
                 m=m, s=s, halos=halos,
                 sweep=state.sweep + sched.shape[0] * sched.shape[1],
                 flips=flips_publish(state.flips, fl))
-            return st, health
-
-        self._chunk_cache[key] = lattice_chunk
-        return lattice_chunk
-
-    def _run_chunk_bp_deg(self, iters: int, S: int, freeze: bool,
-                          has_codes: bool):
-        """Bitplane chunk runner with the integrity layer on."""
-        key = ("bp-deg", iters, S, freeze, has_codes)
-        if key in self._chunk_cache:
-            return self._chunk_cache[key]
-        spec_w, spec_m = self.spec_m, self.spec_m
-        spec_masks, spec_flat = self.spec_masks_w, self.spec_flat
-        hspecs = self.halo_specs
-        axes_all = self._axes_all()
-        R = self.replicas
-        hlspec = tuple(P() for _ in range(6))
-
-        def block(mw, s, halos, sched, masks_w, signs, nz, base, lut,
-                  health, *rest):
-            codes = rest[0] if has_codes else None
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, 0], xhi[:, 0], ylo[:, :, 0, :], yhi[:, :, 0, :],
-                     zlo[:, :, :, 0], zhi[:, :, :, 0])
-            local = jnp.zeros((R,), jnp.uint32)
-
-            def it(carry, b):
-                mw, s, halos, fl, health = carry
-                mw, s, f = pbit_bitplane_sweep_op(
-                    mw, s, b, masks_w, signs, nz, base, halos, lut,
-                    impl=self.impl)
-                halos, health = self._exchange_block_checked(
-                    mw, halos, health, codes, freeze)
-                return (mw, s, halos, fl + f.astype(jnp.uint32), health), None
-            (mw, s, halos, local, health), _ = jax.lax.scan(
-                it, (mw, s, halos, local, health), sched)
-            flips = jax.lax.psum(local, axes_all) if axes_all else local
-            health = self._health_pmax(health, axes_all)
-            xlo, xhi, ylo, yhi, zlo, zhi = halos
-            halos = (xlo[:, None], xhi[:, None],
-                     ylo[:, :, None, :], yhi[:, :, None, :],
-                     zlo[:, :, :, None], zhi[:, :, :, None])
-            return mw, s, halos, flips, health
-
-        in_specs = (spec_w, spec_m, hspecs, P(), spec_masks,
-                    tuple(spec_flat for _ in range(6)),
-                    tuple(spec_flat for _ in range(6)), spec_flat, P(),
-                    hlspec)
-        if has_codes:
-            in_specs = in_specs + (P(),)
-        smapped = shard_map(
-            block, mesh=self.mesh, in_specs=in_specs,
-            out_specs=(spec_w, spec_m, hspecs, P(), hlspec),
-            check_vma=False,
-        )
-
-        @jax.jit
-        def lattice_chunk(state: BitplaneLatticeState, sched, masks_w,
-                          signs, nz, base, lut, health, *rest):
-            mw, s, halos, fl, health = smapped(
-                state.m, state.s, state.halos, sched, masks_w, signs, nz,
-                base, lut, health, *rest)
-            st = BitplaneLatticeState(
-                m=mw, s=s, halos=halos,
-                sweep=state.sweep + sched.shape[0] * sched.shape[1],
-                flips=flips_publish(state.flips, fl))
-            return st, health
+            return (st, health[0]) if check is not None else st
 
         self._chunk_cache[key] = lattice_chunk
         return lattice_chunk
@@ -1103,73 +890,34 @@ class LatticeDSIM:
         beta_arr = np.asarray(schedule.beta_array(), np.float32)
         per_rep = beta_arr.ndim == 2
 
-        deg = self.degrade is not None
-        if deg:
+        if self.precision == "f32":
+            sched = ArraySchedule(beta_arr) if per_rep else schedule
+            lut_opt = ()
+        else:
+            table = beta_table(beta_arr)
+            lut_opt = (self._lut_for(table),)
+            sched = ArraySchedule(beta_row_indices(beta_arr, table))
+        check = None
+        if self.degrade is not None:
             self.health.reset()
             codes = self._fault_codes
-            freeze = self.degrade.mode == "freeze_boundary"
-            has_codes = codes is not None
-            code_args = (codes,) if has_codes else ()
+            check = (self.degrade.mode == "freeze_boundary", codes is not None)
+            codes_opt = () if codes is None else (codes,)
+        field = self._field
 
-        if self.precision == "bitplane":
-            table = beta_table(beta_arr)
-            lut = self._lut_for(table)
-            sched = ArraySchedule(beta_row_indices(beta_arr, table))
-
-            if deg:
-                def chunk(st, rows2d, iters, S):
-                    st, carry = self._run_chunk_bp_deg(
-                        iters, S, freeze, has_codes)(
-                            st, rows2d, self.masks_w, self.signs6_w,
-                            self.nz6_w, self.base_w, lut,
-                            self.health.carry, *code_args)
-                    self.health.update(carry, exchanges=iters)
-                    return st
-            else:
-                def chunk(st, rows2d, iters, S):
-                    return self._run_chunk_bp(iters, S)(
-                        st, rows2d, self.masks_w, self.signs6_w,
-                        self.nz6_w, self.base_w, lut)
-        elif self.precision == "int8":
-            table = beta_table(beta_arr)
-            lut = self._lut_for(table)
-            sched = ArraySchedule(beta_row_indices(beta_arr, table))
-
-            if deg:
-                def chunk(st, rows2d, iters, S):
-                    st, carry = self._run_chunk_deg(
-                        iters, S, per_rep, freeze, has_codes)(
-                            st, rows2d, self.p.masks, self.h_q, self.w6_q,
-                            self.health.carry, *(code_args + (lut,)))
-                    self.health.update(carry, exchanges=iters)
-                    return st
-            else:
-                def chunk(st, rows2d, iters, S):
-                    return self._run_chunk(iters, S, per_rep)(
-                        st, rows2d, self.p.masks, self.h_q, self.w6_q, lut)
-        else:
-            sched = ArraySchedule(beta_arr) if per_rep else schedule
-
-            if deg:
-                def chunk(st, betas2d, iters, S):
-                    st, carry = self._run_chunk_deg(
-                        iters, S, per_rep, freeze, has_codes)(
-                            st, betas2d, self.p.masks, self.p.h, self.p.w6,
-                            self.health.carry, *code_args)
-                    self.health.update(carry, exchanges=iters)
-                    return st
-            else:
-                def chunk(st, betas2d, iters, S):
-                    return self._run_chunk(iters, S, per_rep)(
-                        st, betas2d, self.p.masks, self.p.h, self.p.w6)
-
-        def counted(st, sched2d, iters, S):
+        def chunk(st, sched2d, iters, S):
             exchanges.count(self.link, iters)     # one per S sweeps
-            return chunk(st, sched2d, iters, S)
+            if check is None:
+                return self._run_chunk(iters, S, per_rep)(st, sched2d, field,
+                                                          *lut_opt)
+            st, carry = self._run_chunk(iters, S, per_rep, check=check)(
+                st, sched2d, field, *lut_opt, self.health.carry, *codes_opt)
+            self.health.update(carry, exchanges=iters)
+            return st
 
         kw = dict(
             state=state, schedule=sched, record_points=record_points,
-            chunk_fn=counted, record_fn=self.energy, sync_every=int(sync_every),
+            chunk_fn=chunk, record_fn=self.energy, sync_every=int(sync_every),
             flips_of=lambda st: st.flips,
             flips_per_sweep=self.n_sites * self.replicas)
         if cursor:
@@ -1199,7 +947,7 @@ class LatticeDSIM:
                     # energy readout — identical float ops to the unpacked
                     # engines, so equal spins give equal energies
                     halos = tuple(unpack_lanes(hw, R)
-                                  for hw in self._exchange_block_w(m))
+                                  for hw in self._exchange_block(m))
                     m = unpack_lanes(m, R)
                 else:
                     halos = self._exchange_block(m)
@@ -1236,83 +984,36 @@ class LatticeDSIM:
                     has_codes: bool = False):
         """(runner, abstract args) for one sampling chunk — shared by the
         lowering dry-run and the static contract auditor's tracer.  With
-        ``degrade`` the checked-exchange runner (per-face health carry,
-        optional fault-code operand) is selected instead of the plain one."""
-        def sds(x, spec):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+        ``degrade`` the runner runs checked (per-face health carry,
+        optional fault-code operand)."""
+        def sds(shape, dtype, spec=P()):
+            return jax.ShapeDtypeStruct(tuple(shape), dtype,
                                         sharding=self._shard(spec))
-        p = self.p
-        X, Y, Z = p.dims
+        X, Y, Z = self.p.dims
         R = self.replicas
-        health = tuple(
-            jax.ShapeDtypeStruct(np.shape(h), np.asarray(h).dtype,
-                                 sharding=self._shard(P()))
-            for h in health_init(6)) if degrade else None
-        codes_opt = (jax.ShapeDtypeStruct((8,), jnp.uint32,
-                                          sharding=self._shard(P())),) \
-            if has_codes else ()
-        if self.precision == "bitplane":
-            st = BitplaneLatticeState(
-                m=jax.ShapeDtypeStruct((self.words, X, Y, Z), jnp.uint32,
-                                       sharding=self._shard(self.spec_m)),
-                s=jax.ShapeDtypeStruct((R, X, Y, Z), jnp.uint32,
-                                       sharding=self._shard(self.spec_m)),
-                halos=tuple(jax.ShapeDtypeStruct(tuple(sh), jnp.uint32,
-                                                 sharding=self._shard(sp))
-                            for sh, sp in zip(self._halo_shapes(),
-                                              self.halo_specs)),
-                sweep=jax.ShapeDtypeStruct((), jnp.int32,
-                                           sharding=self._shard(P())),
-                flips=jax.ShapeDtypeStruct((R,), jnp.int32,
-                                           sharding=self._shard(P())),
-            )
-            rows = jax.ShapeDtypeStruct((iters, S), jnp.int32,
-                                        sharding=self._shard(P()))
-            masks_w = sds(self.masks_w, self.spec_masks_w)
-            signs = tuple(sds(w, self.spec_flat) for w in self.signs6_w)
-            nz = tuple(sds(w, self.spec_flat) for w in self.nz6_w)
-            base = sds(self.base_w, self.spec_flat)
-            lut = jax.ShapeDtypeStruct((lut_rows, 2 * self.f_max + 1),
-                                       jnp.uint32, sharding=self._shard(P()))
-            if degrade:
-                run = self._run_chunk_bp_deg(iters, S, freeze, has_codes)
-                return run, (st, rows, masks_w, signs, nz, base, lut,
-                             health) + codes_opt
-            return self._run_chunk_bp(iters, S), \
-                (st, rows, masks_w, signs, nz, base, lut)
-        st = LatticeState(
-            m=jax.ShapeDtypeStruct((R, X, Y, Z), jnp.int8,
-                                   sharding=self._shard(self.spec_m)),
-            s=jax.ShapeDtypeStruct((R, X, Y, Z), jnp.uint32,
-                                   sharding=self._shard(self.spec_m)),
-            halos=tuple(jax.ShapeDtypeStruct(tuple(sh), jnp.int8,
-                                             sharding=self._shard(sp))
-                        for sh, sp in zip(self._halo_shapes(), self.halo_specs)),
-            sweep=jax.ShapeDtypeStruct((), jnp.int32, sharding=self._shard(P())),
-            flips=jax.ShapeDtypeStruct((R,), jnp.int32,
-                                       sharding=self._shard(P())),
-        )
-        masks = sds(p.masks, self.spec_masks)
-        if self.precision == "int8":
-            sched = jax.ShapeDtypeStruct((iters, S), jnp.int32,
-                                         sharding=self._shard(P()))
-            hh = sds(self.h_q, self.spec_flat)
-            ww = tuple(sds(w, self.spec_flat) for w in self.w6_q)
-            lut_opt = (jax.ShapeDtypeStruct((lut_rows, 2 * self.f_max + 1),
-                                            jnp.uint32,
-                                            sharding=self._shard(P())),)
+        bitplane = self.precision == "bitplane"
+        dt = jnp.uint32 if bitplane else jnp.int8
+        cls = BitplaneLatticeState if bitplane else LatticeState
+        st = cls(
+            m=sds((self.words if bitplane else R, X, Y, Z), dt, self.spec_m),
+            s=sds((R, X, Y, Z), jnp.uint32, self.spec_m),
+            halos=tuple(sds(sh, dt, sp) for sh, sp in
+                        zip(self._halo_shapes(), self.halo_specs)),
+            sweep=sds((), jnp.int32), flips=sds((R,), jnp.int32))
+        field = jax.tree.map(lambda x, sp: sds(np.shape(x), x.dtype, sp),
+                             self._field, self._field_specs)
+        if self.precision == "f32":
+            args = (st, sds((iters, S), jnp.float32), field)
         else:
-            sched = jax.ShapeDtypeStruct((iters, S), jnp.float32,
-                                         sharding=self._shard(P()))
-            hh = sds(p.h, self.spec_flat)
-            ww = tuple(sds(w, self.spec_flat) for w in p.w6)
-            lut_opt = ()
-        if degrade:
-            run = self._run_chunk_deg(iters, S, False, freeze, has_codes)
-            return run, (st, sched, masks, hh, ww, health) \
-                + codes_opt + lut_opt
-        return self._run_chunk(iters, S), \
-            (st, sched, masks, hh, ww) + lut_opt
+            args = (st, sds((iters, S), jnp.int32), field,
+                    sds((lut_rows, 2 * self.f_max + 1), jnp.uint32))
+        if not degrade:
+            return self._run_chunk(iters, S), args
+        args += (tuple(sds(np.shape(h), np.asarray(h).dtype)
+                       for h in health_init(6)),)
+        if has_codes:
+            args += (sds((8,), jnp.uint32),)
+        return self._run_chunk(iters, S, check=(freeze, has_codes)), args
 
     def lower_chunk(self, iters: int = 2, S: int = 4, lut_rows: int = 10):
         """Lower (not run) one sampling chunk — used by the launch dry-run."""

@@ -1,5 +1,4 @@
-"""Serving layer: the async multi-tenant sampling server and the LM
-token paths.
+"""Serving layer: the async multi-tenant sampling server.
 
 - :class:`SampleServer` (server.py) — async job queue with priorities and
   admission control, replica-packing scheduler, LRU engine pool, and
@@ -10,7 +9,6 @@ token paths.
   serving failure taxonomy, and the checkpoint spool behind
   ``SampleServer.recover`` (see DESIGN.md, "Fault tolerance and
   recovery").
-- serve_step.py — prefill/decode steps for the LM workload family.
 """
 
 from .faults import (DeadlineExceeded, FaultPlan, FaultRule,

@@ -16,7 +16,6 @@ from repro.core.energy import energy
 from repro.core.packing import pack_pm1, unpack_pm1
 from repro.core.gibbs import chunk_plan
 from repro.core.pbit import FixedPoint, quantize
-from repro.train.optimizer import q8_encode, q8_decode
 from repro.launch.roofline import _shape_bytes, _group_size
 
 SET = dict(deadline=None, max_examples=20,
@@ -96,21 +95,6 @@ def test_fixedpoint_properties(ib, fb, x):
     # within half a step when in range
     if fmt.lo <= x <= fmt.hi:
         assert abs(q - x) <= fmt.step / 2 + 1e-9
-
-
-@given(st.integers(1, 400), st.integers(0, 10 ** 6))
-@settings(**SET)
-def test_q8_error_bound(n, seed):
-    x = np.random.default_rng(seed).normal(0, 3, n).astype(np.float32)
-    q, s = q8_encode(jnp.asarray(x))
-    y = np.asarray(q8_decode(q, s, (n,)))
-    # blockwise absmax: per-block error <= blockmax/127 (+eps)
-    pad = (-n) % 128
-    xp = np.pad(x, (0, pad)).reshape(-1, 128)
-    bm = np.abs(xp).max(axis=1)
-    err = np.abs(np.pad(x, (0, pad)).reshape(-1, 128) -
-                 np.pad(y, (0, pad)).reshape(-1, 128))
-    assert (err <= bm[:, None] / 127.0 + 1e-5).all()
 
 
 @given(st.integers(2, 30), st.integers(3, 6), st.integers(0, 10 ** 5))

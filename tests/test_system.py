@@ -1,5 +1,4 @@
-"""End-to-end system behaviour: the paper's claims at smoke scale, plus the
-launch-layer pieces that run in-process (config registry, cell enumeration)."""
+"""End-to-end system behaviour: the paper's claims at smoke scale."""
 
 import numpy as np
 import jax
@@ -14,8 +13,6 @@ from repro.core.gibbs import GibbsEngine
 from repro.core.annealing import ea_schedule
 from repro.core.analysis import fit_kappa, time_to_target
 from repro.problems.ea3d import GroundStore
-from repro.configs import list_configs, get_config
-from repro.configs.base import SHAPES
 
 
 def test_eta_monotonicity_smoke():
@@ -92,22 +89,3 @@ def test_throughput_accuracy_tradeoff():
     tt_fast = time_to_target(t_fast, (E_fast - Eg) / g.n, easy)
     assert np.isfinite(tt_fast)
     assert tt_fast < tt_exact
-
-
-def test_all_cells_enumerate_correctly():
-    cfgs = list_configs()
-    lm = {n: c for n, c in cfgs.items() if c.family != "ising"}
-    assert len(lm) == 10
-    long_capable = sorted(n for n, c in lm.items() if c.long_context)
-    assert long_capable == ["h2o-danube-1.8b", "jamba-v0.1-52b",
-                            "mamba2-370m"]
-    cells = sum(len(c.shapes()) for c in lm.values())
-    assert cells == 10 * 3 + 3
-    assert "ea3d-1m" in cfgs
-
-
-def test_decode_cells_are_serve_shapes():
-    for name in ("decode_32k", "long_500k"):
-        assert SHAPES[name].kind == "decode"
-    assert SHAPES["train_4k"].kind == "train"
-    assert SHAPES["prefill_32k"].kind == "prefill"

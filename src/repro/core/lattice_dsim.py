@@ -43,7 +43,7 @@ from .pbit import (FixedPoint, LUT_SELECT_MAX_WIDTH, bitplane_planes,
                    field_bound, flips_publish, lfsr_states,
                    quantize_couplings, threshold_lut_cached)
 from repro.compat import shard_map
-from repro.obs import exchanges, programs
+from repro.obs import exchanges, operand_puts, programs
 from repro.obs.trace import span
 from repro.engines.base import (RecordedCursor, check_lanes,
                                 run_recorded_driver, spawn_seeds)
@@ -401,6 +401,10 @@ class LatticeDSIM:
         self.halo_specs = tuple(P(None, ax, ay, az) for _ in range(6))
         self._field, self._field_specs = self._field_operands()
         self._shard = lambda spec: NamedSharding(mesh, spec)
+        # the field and energy operands as put on the mesh, filled on
+        # first use (never here: an engine over an AbstractMesh has no
+        # devices) and dropped by ``shard_state``, with the LUTs
+        self._placed = {}
         self._chunk_cache = {}
         self._energy_fn = None
         self._exchange_only_fn = None
@@ -420,9 +424,31 @@ class LatticeDSIM:
         return "fused" if self.fused else "per_phase"
 
     def _lut_for(self, table: np.ndarray) -> jnp.ndarray:
-        """Threshold LUT for a beta table (cached; fmt folded in)."""
-        return threshold_lut_cached(self._lut_cache, table, self.q_scale,
-                                    self.f_max, fmt=self.fmt)
+        """Threshold LUT for a beta table (cached; fmt folded in), put
+        replicated on the mesh."""
+        return threshold_lut_cached(
+            self._lut_cache, table, self.q_scale, self.f_max, fmt=self.fmt,
+            put=lambda t: self._put(t, P(), "lut"))
+
+    def _put(self, x, spec, operand: str):
+        """``x`` (a pytree of arrays, specs ``spec``) put from the host in
+        the sharding its program declares, so that jit dispatches it with
+        no reshard; counted in ``lattice_operand_puts_total{operand}``."""
+        operand_puts.count(operand)
+        return jax.tree.map(
+            lambda a, sp: jax.device_put(np.asarray(a), self._shard(sp)),
+            x, spec)
+
+    def _operands(self, operand: str):
+        """The ``"field"`` operands of the chunk or the ``"energy"`` ones
+        of the readout (active sites, h, w6), put on the mesh once."""
+        if operand not in self._placed:
+            p, flat = self.p, self.spec_flat
+            value, spec = (self._field, self._field_specs) \
+                if operand == "field" else \
+                ((p.active, p.h, tuple(p.w6)), (flat, flat, (flat,) * 6))
+            self._placed[operand] = self._put(value, spec, operand)
+        return self._placed[operand]
 
     def _field_operands(self):
         """The chunk's field operands ``(masks, h, w6)`` and their specs,
@@ -786,7 +812,7 @@ class LatticeDSIM:
         if self.degrade is None:
             raise ValueError("set_exchange_faults needs a degrade policy "
                              "(unchecked engines must not ingest damage)")
-        self._fault_codes = jnp.asarray(np.asarray(codes), jnp.int32)
+        self._fault_codes = np.asarray(codes, np.int32)
 
     def resync(self, state):
         """Quarantine exit: instantaneous full-boundary refresh.
@@ -835,10 +861,12 @@ class LatticeDSIM:
                        flips=flips)
 
     def shard_state(self, st):
-        # drop the cached exchange closures: they closed over the old
-        # sharding, and a restore()/re-shard must not probe stale layouts
+        # drop the cached exchange closures and the operands put on the
+        # mesh: they hold the old sharding, and a restore()/re-shard must
+        # not probe stale layouts
         self._exchange_only_fn = None
         self._halo_refresh = None
+        self._placed, self._lut_cache = {}, {}
         put = jax.device_put
         cls = type(st)
         # bitplane words lead with the W stacked planes, unpacked spins
@@ -898,20 +926,25 @@ class LatticeDSIM:
             lut_opt = (self._lut_for(table),)
             sched = ArraySchedule(beta_row_indices(beta_arr, table))
         check = None
+        replicated = self._shard(P())
         if self.degrade is not None:
             self.health.reset()
             codes = self._fault_codes
             check = (self.degrade.mode == "freeze_boundary", codes is not None)
-            codes_opt = () if codes is None else (codes,)
-        field = self._field
+            codes_opt = () if codes is None else \
+                (jax.device_put(codes, replicated),)
+        field = self._operands("field")
 
         def chunk(st, sched2d, iters, S):
             exchanges.count(self.link, iters)     # one per S sweeps
+            sched2d = self._put(sched2d, P(), "sched")
             if check is None:
                 return self._run_chunk(iters, S, per_rep)(st, sched2d, field,
                                                           *lut_opt)
+            # a fresh carry is on the host; a chunk's is already in place
+            health = jax.device_put(self.health.carry, replicated)
             st, carry = self._run_chunk(iters, S, per_rep, check=check)(
-                st, sched2d, field, *lut_opt, self.health.carry, *codes_opt)
+                st, sched2d, field, *lut_opt, health, *codes_opt)
             self.health.update(carry, exchanges=iters)
             return st
 
@@ -963,7 +996,7 @@ class LatticeDSIM:
                 in_specs=(self.spec_m, self.spec_flat, self.spec_flat,
                           tuple(self.spec_flat for _ in range(6))),
                 out_specs=P(), check_vma=False))
-        e = self._energy_fn(state.m, self.p.active, self.p.h, self.p.w6)
+        e = self._energy_fn(state.m, *self._operands("energy"))
         return e[0] if self.replicas == 1 else e
 
     def global_spins(self, state) -> jnp.ndarray:

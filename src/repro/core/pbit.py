@@ -280,13 +280,14 @@ def threshold_lut(betas, scale: float, f_max: int,
 
 
 def threshold_lut_cached(cache: dict, table: np.ndarray, scale: float,
-                         f_max: int,
-                         fmt: Optional[FixedPoint] = None) -> jnp.ndarray:
+                         f_max: int, fmt: Optional[FixedPoint] = None,
+                         put=jnp.asarray) -> jnp.ndarray:
     """Device-resident :func:`threshold_lut`, memoized in the caller-owned
     ``cache`` — the one LUT-construction path shared by every engine.  The
     key covers everything that determines the table, so one cache dict may
-    be shared across problems."""
+    be shared across problems.  ``put`` takes the host table to the
+    device (a mesh engine puts it in the sharding its programs declare)."""
     key = (table.tobytes(), float(scale), int(f_max), fmt)
     if key not in cache:
-        cache[key] = jnp.asarray(threshold_lut(table, scale, f_max, fmt=fmt))
+        cache[key] = put(threshold_lut(table, scale, f_max, fmt=fmt))
     return cache[key]

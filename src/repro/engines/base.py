@@ -405,10 +405,11 @@ class RecordedCursor:
                 self._snapshot()
             self._wait_in_flight()
             with span("cursor.chunk"):
-                # trailing dims (e.g. a per-replica axis) ride along untouched
-                bchunk = jnp.asarray(
-                    self._betas[self._pos:self._pos + nsw]).reshape(
-                        (c, self.S) + self._betas.shape[1:])
+                # trailing dims (e.g. a per-replica axis) ride along
+                # untouched; the slice stays on the host, for the chunk to
+                # put where its program wants it
+                bchunk = self._betas[self._pos:self._pos + nsw].reshape(
+                    (c, self.S) + self._betas.shape[1:])
                 if self.chunk_timer is not None:
                     jax.block_until_ready(self.state)
                     t0 = time.perf_counter()
@@ -470,7 +471,7 @@ class RecordedCursor:
                 continue
             seen.add(c)
             nsw = c * self.S
-            bchunk = jnp.asarray(self._betas[:nsw]).reshape(
+            bchunk = self._betas[:nsw].reshape(
                 (c, self.S) + self._betas.shape[1:])
             jax.block_until_ready(self._chunk_fn(self.state, bchunk, c,
                                                  self.S))
@@ -568,7 +569,8 @@ def run_recorded_driver(*, state, schedule, record_points: Sequence[int],
       schedule: a ``repro.core.annealing.Schedule``.
       record_points: sweep indices at which to record (non-empty).
       chunk_fn: ``(state, betas_2d, iters, S) -> state`` runs ``iters``
-        iterations of ``S`` sweeps; betas_2d has shape (iters, S).
+        iterations of ``S`` sweeps; betas_2d is a host array of shape
+        (iters, S), which jit takes as it is or the chunk puts itself.
       record_fn: ``state -> observable`` read at each record point.
       sync_every: int S (exchange every S sweeps), 'phase', or None —
         engines that don't exchange just ignore it in their chunk_fn.

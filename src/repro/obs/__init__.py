@@ -2,7 +2,7 @@
 
 Stdlib, the repo's own commcost model and jax's profiler annotations
 (the rest of jax is imported lazily, at explicit sync boundaries).
-Six layers:
+Seven layers:
 
 * :mod:`.metrics` — thread-safe :class:`MetricsRegistry` of counters,
   gauges, and fixed-bucket histograms with labeled children, JSON
@@ -16,6 +16,9 @@ Six layers:
   settles and in-flight waits (``cursor_flip_syncs_total{kind}``);
 * :mod:`.exchanges` — the lattice engine's halo exchanges dispatched,
   across devices or within one (``lattice_exchanges_total{link}``);
+* :mod:`.operand_puts` — the lattice engine's operands put in the
+  shardings its programs declare (``lattice_operand_puts_total
+  {operand}``);
 * :mod:`.timing` — :class:`EtaMeter`, which turns per-chunk wall time
   plus exchange-only collective time into measured η = f_comm/f_pbit
   and its margin against ``commcost.eta_threshold``.
